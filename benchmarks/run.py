@@ -138,6 +138,8 @@ def main() -> None:
     if args:
         if smoke:
             os.environ["BENCH_SMOKE"] = "1"  # before the module import
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
         run_module(args[0])
         return
     print("name,us_per_call,derived", flush=True)

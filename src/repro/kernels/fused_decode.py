@@ -72,17 +72,18 @@ def _make_kernel(activation: str, shift: float, glu: bool, masked: bool):
 
         @pl.when(i < nvalid_ref[t])
         def _acc():
+            x = x_ref[0]  # (1, d)
             h = act_fn(jax.lax.dot_general(
-                x_ref[...], wg_ref[...], (((1,), (0,)), ((), ())),
+                x, wg_ref[...], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
             if glu:
                 h = h * jax.lax.dot_general(
-                    x_ref[...], wu_ref[...], (((1,), (0,)), ((), ())),
+                    x, wu_ref[...], (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
             if masked:
-                h = h * m_ref[...]
-            h_ref[...] = h[:, None, :]
-            y_ref[...] += jax.lax.dot_general(
+                h = h * m_ref[0]
+            h_ref[0, 0] = h
+            y_ref[0] += jax.lax.dot_general(
                 h.astype(wd_ref.dtype), wd_ref[...], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
@@ -121,9 +122,13 @@ def fused_sparse_ffn(x, w_gate, wd, idx, nvalid, *, w_up=None, unit_mask=None,
     glu = w_up is not None
     masked = unit_mask is not None
 
+    # per-token operands carry a unit second-minor axis ((T, 1, d) with
+    # block (1, 1, d)) so every block's last two dims either equal the
+    # array's or are (8, 128)-aligned, as the TPU lowering requires
     tile_spec = pl.BlockSpec((d, tile), lambda t, i, idx, nv: (0, idx[t, i]))
-    in_specs = [pl.BlockSpec((1, d), lambda t, i, idx, nv: (t, 0)), tile_spec]
-    args = [x, w_gate]
+    in_specs = [pl.BlockSpec((1, 1, d), lambda t, i, idx, nv: (t, 0, 0)),
+                tile_spec]
+    args = [x[:, None, :], w_gate]
     if glu:
         in_specs.append(tile_spec)
         args.append(w_up)
@@ -131,29 +136,31 @@ def fused_sparse_ffn(x, w_gate, wd, idx, nvalid, *, w_up=None, unit_mask=None,
         pl.BlockSpec((tile, d_out), lambda t, i, idx, nv: (idx[t, i], 0)))
     args.append(wd)
     if masked:
-        in_specs.append(
-            pl.BlockSpec((1, tile), lambda t, i, idx, nv: (t, idx[t, i])))
-        args.append(unit_mask.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, tile), lambda t, i, idx, nv: (t, 0, idx[t, i])))
+        args.append(unit_mask.astype(jnp.float32)[:, None, :])
 
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T, K),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, d_out), lambda t, i, idx, nv: (t, 0)),
-            pl.BlockSpec((1, 1, tile), lambda t, i, idx, nv: (t, i, 0)),
+            pl.BlockSpec((1, 1, d_out), lambda t, i, idx, nv: (t, 0, 0)),
+            pl.BlockSpec((1, 1, 1, tile),
+                         lambda t, i, idx, nv: (t, i, 0, 0)),
         ],
     )
     y, compact = pl.pallas_call(
         _make_kernel(activation, shift, glu, masked),
         grid_spec=spec,
+        name="fused_sparse_ffn",
         out_shape=[
-            jax.ShapeDtypeStruct((T, d_out), jnp.float32),
-            jax.ShapeDtypeStruct((T, K, tile), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, d_out), jnp.float32),
+            jax.ShapeDtypeStruct((T, K, 1, tile), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(idx.astype(jnp.int32), nvalid.astype(jnp.int32), *args)
-    return y, compact
+    return y[:, 0], compact[:, :, 0]
 
 
 def scatter_compact(compact, idx, nvalid, n_tiles: int):

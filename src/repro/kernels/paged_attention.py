@@ -56,12 +56,12 @@ def _make_kernel(bs: int, window: int):
         logits = jax.lax.dot_general(  # (kvp, Wg, bs)
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        qpos = pos_ref[0]  # (Wg,) int32 absolute position per query row
+        qpos = pos_ref[0]  # (Wg, 1) int32 absolute position per query row
         kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (q.shape[1], bs),
                                                  1)
-        valid = kpos <= qpos[:, None]
+        valid = kpos <= qpos
         if window:
-            valid &= kpos > qpos[:, None] - window
+            valid &= kpos > qpos - window
         logits = jnp.where(valid[None], logits, -1e30)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
@@ -105,7 +105,10 @@ def paged_window_attention(q, k_pages, v_pages, table, pos, *,
     # compute logits in the cache dtype with f32 accumulation
     qs = (q * jnp.asarray(scale, q.dtype)).astype(k_pages.dtype)
     qs = qs.transpose(0, 2, 1, 3, 4).reshape(b, kvp, W * g, hd)
-    posr = jnp.repeat(pos.astype(jnp.int32), g, axis=1)  # (b, W*g)
+    # (b, W*g, 1): a column per slot, so the block's last two dims equal the
+    # array's (TPU lowering rule) and it broadcasts against the key
+    # positions without a relayout
+    posr = jnp.repeat(pos.astype(jnp.int32), g, axis=1)[:, :, None]
 
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -116,7 +119,7 @@ def paged_window_attention(q, k_pages, v_pages, table, pos, *,
                          lambda t, j, tbl: (tbl[t, j], 0, 0, 0)),
             pl.BlockSpec((1, kvp, bs, hd),
                          lambda t, j, tbl: (tbl[t, j], 0, 0, 0)),
-            pl.BlockSpec((1, W * g), lambda t, j, tbl: (t, 0)),
+            pl.BlockSpec((1, W * g, 1), lambda t, j, tbl: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, kvp, W * g, hd),
                                lambda t, j, tbl: (t, 0, 0, 0)),
@@ -129,6 +132,7 @@ def paged_window_attention(q, k_pages, v_pages, table, pos, *,
     o = pl.pallas_call(
         _make_kernel(bs, window),
         grid_spec=spec,
+        name="paged_window_attention",
         out_shape=jax.ShapeDtypeStruct((b, kvp, W * g, hd), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(table.astype(jnp.int32), qs, k_pages, v_pages, posr)

@@ -116,8 +116,8 @@ def _kernel_tokens(idx_ref, nvalid_ref, x_ref, w_ref, o_ref):
 
     @pl.when(i < nvalid_ref[t])
     def _acc():
-        o_ref[...] += jax.lax.dot_general(
-            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        o_ref[0] += jax.lax.dot_general(
+            x_ref[0], w_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
 
@@ -139,24 +139,28 @@ def sparse_matmul_tokens(x, w, idx, nvalid, *, tile: int = 128,
     block_d = min(block_d, D)
     assert F % tile == 0 and D % block_d == 0
 
+    # rows ride a unit second-minor axis ((T, 1, F), block (1, 1, tile)):
+    # the TPU lowering needs each block's last two dims aligned or whole
     grid = (T, D // block_d, K)
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile), lambda t, j, i, idx, nv: (t, idx[t, i])),
+            pl.BlockSpec((1, 1, tile),
+                         lambda t, j, i, idx, nv: (t, 0, idx[t, i])),
             pl.BlockSpec((tile, block_d),
                          lambda t, j, i, idx, nv: (idx[t, i], j)),
         ],
-        out_specs=pl.BlockSpec((1, block_d),
-                               lambda t, j, i, idx, nv: (t, j)),
+        out_specs=pl.BlockSpec((1, 1, block_d),
+                               lambda t, j, i, idx, nv: (t, 0, j)),
     )
     return pl.pallas_call(
         _kernel_tokens,
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
+        name="sparse_matmul_tokens",
+        out_shape=jax.ShapeDtypeStruct((T, 1, D), jnp.float32),
         interpret=_resolve_interpret(interpret),
-    )(idx.astype(jnp.int32), nvalid.astype(jnp.int32), x, w)
+    )(idx.astype(jnp.int32), nvalid.astype(jnp.int32), x[:, None, :], w)[:, 0]
 
 
 def _kernel_up(idx_ref, nvalid_ref, x_ref, w_ref, o_ref):
@@ -164,9 +168,9 @@ def _kernel_up(idx_ref, nvalid_ref, x_ref, w_ref, o_ref):
 
     @pl.when(i < nvalid_ref[t])
     def _compute():
-        o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[None]
+        o_ref[0, 0] = jax.lax.dot_general(
+            x_ref[0], w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(i >= nvalid_ref[t])
     def _zero():  # padded iterations: no MXU work, block zeroed
@@ -194,22 +198,27 @@ def sparse_up_matmul(x, w, idx, nvalid, *, tile: int = 128, interpret=None):
     assert F % tile == 0
     n_tiles = F // tile
 
+    # unit second-minor axes ((T, 1, d) in, (T, K, 1, tile) out) keep every
+    # block's last two dims whole or aligned for the TPU lowering
     grid = (T, K)
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, d), lambda t, i, idx, nv: (t, 0)),
+            pl.BlockSpec((1, 1, d), lambda t, i, idx, nv: (t, 0, 0)),
             pl.BlockSpec((d, tile), lambda t, i, idx, nv: (0, idx[t, i])),
         ],
-        out_specs=pl.BlockSpec((1, 1, tile), lambda t, i, idx, nv: (t, i, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, tile),
+                               lambda t, i, idx, nv: (t, i, 0, 0)),
     )
     compact = pl.pallas_call(
         _kernel_up,
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((T, K, tile), jnp.float32),
+        name="sparse_up_matmul",
+        out_shape=jax.ShapeDtypeStruct((T, K, 1, tile), jnp.float32),
         interpret=_resolve_interpret(interpret),
-    )(idx.astype(jnp.int32), nvalid.astype(jnp.int32), x, w)
+    )(idx.astype(jnp.int32), nvalid.astype(jnp.int32), x[:, None, :], w)
+    compact = compact[:, :, 0]
     valid = (jnp.arange(K)[None, :] < nvalid[:, None]).astype(jnp.float32)
     compact = compact * valid[:, :, None]
     y = jnp.zeros((T, n_tiles, tile), jnp.float32)

@@ -5,9 +5,8 @@ jax device state. The dry-run entry point (launch/dryrun.py) sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before importing jax;
 smoke tests and benchmarks see the single real CPU device.
 
-``_make_mesh`` papers over the jax version split: explicit axis types
-(jax.sharding.AxisType) exist only on jax >= 0.6; on the pinned 0.4.x line
-meshes are implicitly Auto, which is exactly what every caller here wants.
+Every mesh here has explicit Auto axis types: GSPMD places the sharding
+annotations, which is what every caller wants.
 """
 from __future__ import annotations
 
@@ -17,11 +16,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.6: be explicit
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -89,6 +89,8 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import SHAPES, get_config, smoke_config
     from repro.core import relufication
     from repro.models import registry

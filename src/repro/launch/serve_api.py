@@ -107,6 +107,7 @@ def build_engine(args: argparse.Namespace):
     import jax
 
     from repro.configs import get_config, smoke_config
+    from repro.models import common as cm
     from repro.models import registry
     from repro.serving import ContinuousBatchingEngine, EngineConfig
 
@@ -138,7 +139,10 @@ def build_engine(args: argparse.Namespace):
         # tile=1 = exact row-skipping, observable on the tiny smoke models
         kw.update(predictor=calibrate_from_config(params, cfg, calib,
                                                   tile=1))
-    return ContinuousBatchingEngine(cfg, params,
+    # serve from weights stored in the compute dtype: the step's entry cast
+    # (cast_params) is then the identity, and a bf16 model holds half the
+    # f32 master bytes in device memory
+    return ContinuousBatchingEngine(cfg, cm.cast_params(params, cfg),
                                     config=EngineConfig(**kw).validate())
 
 
@@ -418,7 +422,10 @@ async def _amain(args: argparse.Namespace) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
     args = parse_args()
+    use_compile_cache()
     try:
         asyncio.run(_amain(args))
     except KeyboardInterrupt:

@@ -9,7 +9,10 @@ under test, spawned as a real subprocess speaking real HTTP):
      seeds), one of which disconnects mid-stream;
   3. assert every completed greedy stream is byte-identical to an offline
      ``engine.run()`` over a reference engine built with the SAME args
-     (serve_api.build_engine — same random weights, same config);
+     (serve_api.build_engine — same random weights, same config). That
+     reference runs in a child interpreter that exits before the server
+     starts: this driver never imports jax, so on an accelerator the
+     server is the one process holding the chip;
   4. assert the server survives the disconnect: /healthz still answers
      and a post-disconnect greedy request still matches the reference;
   5. scrape ``/metrics`` mid-run (availability under load) and again at
@@ -28,11 +31,13 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import os
 import shutil
 import subprocess
 import sys
 import threading
 import time
+from pathlib import Path
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -163,13 +168,30 @@ def reference_streams(mode: str, reqs: List[dict]) -> dict:
     return {i: [int(t) for t in res[u].tokens] for i, u in uids.items()}
 
 
-async def drive(port: int, mode: str,
+def reference_in_child(mode: str, reqs: List[dict],
+                       timeout: float = CLIENT_TIMEOUT_S) -> dict:
+    """``reference_streams`` run by ``--reference MODE`` in a child
+    interpreter, which exits before this returns; the caller never touches
+    jax. Requests go in on stdin, streams come back as the child's last
+    stdout line."""
+    env = dict(os.environ)
+    src = str(Path(__file__).resolve().parents[2])
+    env["PYTHONPATH"] = os.pathsep.join(
+        filter(None, [src, env.get("PYTHONPATH", "")]))
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve_smoke_client",
+         "--reference", mode], input=json.dumps(reqs), capture_output=True,
+        text=True, env=env, timeout=timeout)
+    if r.returncode != 0:
+        raise RuntimeError(f"reference child failed (rc={r.returncode}):\n"
+                           f"{r.stderr[-3000:]}")
+    streams = json.loads(r.stdout.strip().splitlines()[-1])
+    return {int(i): toks for i, toks in streams.items()}
+
+
+async def drive(port: int, mode: str, reqs: List[dict], ref: dict,
                 metrics_out: Optional[str] = None) -> None:
-    from repro.configs import get_config
     from repro.obs import parse_prometheus
-    vocab = get_config("tiny-relu").vocab_size
-    reqs = workload(vocab)
-    ref = reference_streams(mode, reqs)
     failures: List[str] = []
 
     async def run_one(i: int):
@@ -320,10 +342,25 @@ def main() -> None:
                     help="write the final /metrics scrape here "
                          "(default serve_smoke_metrics_<mode>.prom)")
     ap.add_argument("--boot-timeout", type=float, default=300.0)
+    ap.add_argument("--reference", choices=["plain", "spec", "predictor"],
+                    default=None,
+                    help="child role: read the request list on stdin, "
+                         "print reference_streams as one JSON line, exit")
     args = ap.parse_args()
+    if args.reference:
+        print(json.dumps(reference_streams(args.reference,
+                                           json.load(sys.stdin))))
+        return
     if args.metrics_out is None:
         args.metrics_out = f"serve_smoke_metrics_{args.mode}.prom"
 
+    from repro.configs import get_config
+    reqs = workload(get_config("tiny-relu").vocab_size)
+    try:
+        ref = reference_in_child(args.mode, reqs)
+    except (RuntimeError, subprocess.TimeoutExpired) as e:
+        print(f"serve-smoke FAIL [{args.mode}]: {e}", file=sys.stderr)
+        raise SystemExit(1)
     cmd = [sys.executable, "-u", "-m", "repro.launch.serve_api",
            "--port", "0"] + server_args(args.mode)
     log = open(args.log, "w")
@@ -350,7 +387,7 @@ def main() -> None:
         t = threading.Thread(target=shutil.copyfileobj,
                              args=(proc.stdout, log), daemon=True)
         t.start()
-        asyncio.run(drive(port, args.mode, args.metrics_out))
+        asyncio.run(drive(port, args.mode, reqs, ref, args.metrics_out))
     except BaseException as e:
         print(f"serve-smoke FAIL [{args.mode}]: {e}", file=sys.stderr)
         raise SystemExit(1)

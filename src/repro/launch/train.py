@@ -31,6 +31,8 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import SHAPES, TrainConfig, get_config, smoke_config
     from repro.core import relufication
     from repro.data.pipeline import DataConfig

@@ -279,9 +279,13 @@ def window_attention(
         valid &= jnp.arange(S)[None, None, :] > pos[:, :, None] - window
     logits = jnp.where(valid[:, None, None], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqs,bhsd->bqhgd", w.astype(k_cache.dtype), v_cache,
+    # batch dims lead the output and the window axis moves after the dot:
+    # XLA:CPU has no kernel for a bf16 x bf16 -> f32 dot whose output puts a
+    # free dim (q) ahead of the batch dim h ("bhgqs,bhsd->bqhgd" raised
+    # "Unsupported element type for DotThunk" at g > 1)
+    out = jnp.einsum("bhgqs,bhsd->bhgqd", w.astype(k_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)
 
 
 def decode_attention(

@@ -10,6 +10,16 @@ exactly the API surface these tests use: ``given``, ``settings``, and the
 from __future__ import annotations
 
 import importlib.util
+import os
+
+# Exactness tests compare f32 streams across differently shaped executables
+# (a W = 1 decode step against a W = γ+1 verify window). XLA:CPU's
+# multi-threaded Eigen contraction splits a dot's reduction across threads
+# by shape, so the same row of a (2, K) and a (6, K) product can round
+# differently in the last ulp. Single-threaded Eigen takes that split out
+# of the summation order. Set before any test builds a backend.
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS", ""), "--xla_cpu_multi_thread_eigen=false"]))
 
 if importlib.util.find_spec("hypothesis") is None:  # pragma: no cover - CI has it
     import functools

@@ -1,15 +1,6 @@
 """Distribution tests that need multiple (fake) devices — run in
 subprocesses so the main pytest process keeps its single-device view."""
-import jax
-import pytest
-
 from subproc import run_forced_devices as _run
-
-# these tests build explicit-axis-type meshes, an API newer than the jax
-# this environment may pin; skip (not fail) where it's absent
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="requires jax.sharding.AxisType (jax >= 0.6)")
 
 
 def test_ddp_shard_map_8dev():

@@ -41,7 +41,7 @@ def _full(B, t=1.0, k=0, p=1.0):
 # -- greedy branch -----------------------------------------------------------
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(st.integers(0, 10_000))
 def test_temperature_zero_is_exact_argmax(seed):
     logits = _distinct_logits(seed)
@@ -54,7 +54,7 @@ def test_temperature_zero_is_exact_argmax(seed):
     np.testing.assert_array_equal(np.asarray(lp), np.asarray(want_lp))
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 10_000))
 def test_temperature_to_zero_recovers_argmax(seed):
     """T→0+ through the SAMPLING branch: the scaled distribution collapses
@@ -69,7 +69,7 @@ def test_temperature_to_zero_recovers_argmax(seed):
 # -- support invariants ------------------------------------------------------
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(st.integers(1, V), st.integers(0, 10_000))
 def test_top_k_support(k, seed):
     logits = _distinct_logits(seed)
@@ -83,7 +83,7 @@ def test_top_k_support(k, seed):
         assert set(kept.tolist()) == set(want.tolist())
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(st.floats(0.05, 1.0), st.integers(0, 10_000))
 def test_top_p_support_is_minimal_nucleus(p, seed):
     logits = _distinct_logits(seed)
@@ -104,7 +104,7 @@ def test_top_p_support_is_minimal_nucleus(p, seed):
         assert set(kept.tolist()) == set(want.tolist())
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(1, V), st.floats(0.1, 1.0), st.integers(0, 10_000),
        st.floats(0.2, 3.0))
 def test_filtered_rows_renormalize(k, p, seed, temp):
@@ -126,7 +126,7 @@ def test_filtered_rows_renormalize(k, p, seed, temp):
                                    rtol=1e-5)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(1, 8), st.integers(0, 10_000))
 def test_sampled_token_respects_joint_support(k, seed):
     """top-k AND top-p together: the sample lands in the intersection."""

@@ -6,7 +6,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import TrainConfig, get_config
@@ -57,8 +56,6 @@ def test_checkpoint_roundtrip_and_keep(tmp_path):
     assert extras["step"] == 30
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="requires jax.sharding.AxisType (jax >= 0.6)")
 def test_checkpoint_elastic_reshard(tmp_path):
     """Save under one layout, restore with explicit target shardings."""
     mgr = CheckpointManager(str(tmp_path), async_save=False)
@@ -110,8 +107,6 @@ def test_int8_ef_compression_roundtrip():
     assert err.max() <= float(s) * 0.51 + 1e-6
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="requires jax.sharding.AxisType (jax >= 0.6)")
 def test_ddp_compressed_matches_uncompressed_direction():
     """int8-EF DDP step loss should track the uncompressed step closely."""
     from repro.train.ddp import make_ddp_train_step
