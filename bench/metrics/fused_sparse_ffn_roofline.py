@@ -1,0 +1,17 @@
+"""Percent of fused_sparse_ffn's device time that its work needs at the
+chip's peaks. The cells' requests refresh their tile lists every token
+(no reuse window), so every decoding token names every tile: the work is
+the dense FFN of the layer read once, and each token's products."""
+from bench import readers
+
+
+def read(ctx):
+    mc, count = ctx.mc, ctx.counts("fused_sparse_ffn")
+    tile = 128
+    n_tiles = mc["ffn_dim"] // tile
+
+    def work(step):
+        return count.work(tokens=step.n_decode, d_model=mc["hidden_size"],
+                          tile=tile, tiles_named=n_tiles,
+                          tile_token_pairs=step.n_decode * n_tiles)
+    return readers.roofline(ctx, "fused_sparse_ffn", work)
