@@ -250,20 +250,24 @@ def init_block(rng, cfg: ModelConfig, dtype) -> PyTree:
 
 def apply_block(p, x, cfg: ModelConfig, *, positions, stats, return_kv=False,
                 causal=True):
-    h = post_norm(cm.apply_norm(p["ln1"], x, cfg), cfg)
-    if return_kv:
-        a, kv = apply_attn_full(p["attn"], h, cfg, positions=positions,
-                                stats=stats, return_kv=True, causal=causal)
-    else:
-        a = apply_attn_full(p["attn"], h, cfg, positions=positions,
-                            stats=stats, causal=causal)
-    a = ad_checkpoint.checkpoint_name(a, "attn_out")  # TP all-reduce output
-    x = x + a
-    h = post_norm(cm.apply_norm(p["ln2"], x, cfg), cfg)
-    b, s, d = h.shape
-    f = apply_ffn(p["ffn"], h.reshape(b * s, d), cfg, stats=stats).reshape(b, s, d)
-    f = ad_checkpoint.checkpoint_name(f, "ffn_out")  # TP all-reduce output
-    x = x + f
+    with jax.named_scope("attention"):
+        h = post_norm(cm.apply_norm(p["ln1"], x, cfg), cfg)
+        if return_kv:
+            a, kv = apply_attn_full(p["attn"], h, cfg, positions=positions,
+                                    stats=stats, return_kv=True,
+                                    causal=causal)
+        else:
+            a = apply_attn_full(p["attn"], h, cfg, positions=positions,
+                                stats=stats, causal=causal)
+        a = ad_checkpoint.checkpoint_name(a, "attn_out")  # TP all-reduce
+        x = x + a
+    with jax.named_scope("ffn"):
+        h = post_norm(cm.apply_norm(p["ln2"], x, cfg), cfg)
+        b, s, d = h.shape
+        f = apply_ffn(p["ffn"], h.reshape(b * s, d), cfg,
+                      stats=stats).reshape(b, s, d)
+        f = ad_checkpoint.checkpoint_name(f, "ffn_out")  # TP all-reduce
+        x = x + f
     if cfg.sp_residuals:
         x = rules.constrain(x, "dp", None, "model")
     return (x, kv) if return_kv else x
@@ -312,9 +316,11 @@ def embed_tokens(params, tokens, cfg: ModelConfig, positions):
 
 
 def logits_from(params, x, cfg: ModelConfig):
-    u = params.get("unembed", params["embed"])
-    out = jnp.einsum("...d,vd->...v", x, u.astype(x.dtype))
-    return out + cm.vocab_logit_mask(cfg.vocab_size, u.shape[0]).astype(out.dtype)
+    with jax.named_scope("head"):
+        u = params.get("unembed", params["embed"])
+        out = jnp.einsum("...d,vd->...v", x, u.astype(x.dtype))
+        return out + cm.vocab_logit_mask(cfg.vocab_size,
+                                         u.shape[0]).astype(out.dtype)
 
 
 def forward(params, tokens, cfg: ModelConfig, *, stats: Optional[cm.StatsCollector] = None,
@@ -496,10 +502,11 @@ def apply_attn_window_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
     q, k, v = _qkv(p, x, cfg, pos, stats=stats,
                    input_density=cfg.sparsity.input_tile_density)
     q = q.reshape(b, W, g.kvp, g.group, g.head_dim)
-    k_pages = cm.paged_write_window(k_pages, layer, table, pos, k,
-                                    block_size, valid)
-    v_pages = cm.paged_write_window(v_pages, layer, table, pos, v,
-                                    block_size, valid)
+    with jax.named_scope("kv_write"):
+        k_pages = cm.paged_write_window(k_pages, layer, table, pos, k,
+                                        block_size, valid)
+        v_pages = cm.paged_write_window(v_pages, layer, table, pos, v,
+                                        block_size, valid)
     kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
     vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
     if fast_kernels:
@@ -586,16 +593,19 @@ def apply_block_window_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
                              pos, valid, *, layer, block_size: int, mask,
                              refresh, fast_kernels: bool = False):
     stats = cm.StatsCollector(False)
-    h = post_norm(cm.apply_norm(p["ln1"], x, cfg), cfg)
-    a, k_pages, v_pages = apply_attn_window_paged(
-        p["attn"], h, cfg, k_pages, v_pages, table, pos, valid, layer=layer,
-        block_size=block_size, stats=stats, fast_kernels=fast_kernels)
-    x = x + a
-    h = post_norm(cm.apply_norm(p["ln2"], x, cfg), cfg)
-    f, act, scores, density, udens = apply_ffn_window(
-        p["ffn"], h, cfg, mask=mask, refresh=refresh, valid=valid,
-        fast_kernels=fast_kernels)
-    x = x + f
+    with jax.named_scope("attention"):
+        h = post_norm(cm.apply_norm(p["ln1"], x, cfg), cfg)
+        a, k_pages, v_pages = apply_attn_window_paged(
+            p["attn"], h, cfg, k_pages, v_pages, table, pos, valid,
+            layer=layer, block_size=block_size, stats=stats,
+            fast_kernels=fast_kernels)
+        x = x + a
+    with jax.named_scope("ffn"):
+        h = post_norm(cm.apply_norm(p["ln2"], x, cfg), cfg)
+        f, act, scores, density, udens = apply_ffn_window(
+            p["ffn"], h, cfg, mask=mask, refresh=refresh, valid=valid,
+            fast_kernels=fast_kernels)
+        x = x + f
     return x, k_pages, v_pages, act, scores, density, udens
 
 
@@ -779,83 +789,93 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
     telemetry to the return tuple.
     """
     stats = cm.StatsCollector(False)
-    h = post_norm(cm.apply_norm(p["ln1"], x[:, None], cfg)[:, 0], cfg)
     g = attn_geometry(cfg)
-    q, k, v = _qkv(p["attn"], h[:, None, :], cfg, pos[:, None],
-                   stats=stats, input_density=cfg.sparsity.input_tile_density)
-    q = q.reshape(q.shape[0], g.kvp, g.group, g.head_dim)
-    k_pages = cm.paged_write_token(k_pages, layer, table, pos, k[:, 0],
-                                   block_size)
-    v_pages = cm.paged_write_token(v_pages, layer, table, pos, v[:, 0],
-                                   block_size)
-    kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
-    vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
-    if fast_kernels:
-        from repro.kernels import paged_attention as kpa
-        o = kpa.paged_decode_attention(q, kl, vl, table, pos,
-                                       window=cfg.sliding_window)
-    else:
-        kg = cm.paged_gather(kl, table)
-        vg = cm.paged_gather(vl, table)
-        o = cm.decode_attention(q, kg, vg, pos, window=cfg.sliding_window)
-    a = _attn_out(p["attn"], o.reshape(o.shape[0], 1, g.hp, g.head_dim),
-                  cfg)[:, 0]
-    x = x + a
-
-    from repro.kernels.fused_ffn import tile_activity
-    h = post_norm(cm.apply_norm(p["ln2"], x[:, None], cfg)[:, 0], cfg)
-    if pred is not None:
-        f, act, scores, density, n_active, n_miss = _ffn_decode_predicted(
-            p["ffn"], h, cfg, pred, kind=pred_kind, tile=pred_tile,
-            k_tiles=k_tiles, mask=mask, refresh=refresh,
-            measure=pred_measure, shards=pred_shards,
-            fast_kernels=fast_kernels)
-        x = x + f
-        return x, k_pages, v_pages, act, scores, density, n_active, n_miss
-    act_fn = acts.get(cfg.activation, shift=cfg.sparsity.shift)
-    dens_in = (cfg.sparsity.input_tile_density if cfg.sparsity.enabled
-               else 1.0)
-    dens_ffn = (cfg.sparsity.ffn_tile_density if cfg.sparsity.enabled
-                else 1.0)
-    pf = p["ffn"]
-    eff = mask | refresh[:, None]  # refresh ⇒ all rows participate
-    if fast_kernels and dens_in >= 1.0 and dens_ffn >= 1.0:
-        # AR fast path: the γ-window eff mask IS a per-token active set, so
-        # the whole FFN runs through the fused kernel over eff's tile list
-        # at full capacity — up- AND down-projection reads of fully-masked
-        # tiles are physically skipped; masked-off units inside gathered
-        # tiles are zeroed in-kernel (unit_mask), matching hh * eff.
-        from repro.kernels import fused_decode as kfd
-        from repro.predictor import predictors as preds
-        tile = _ffn_tile(cfg)
-        n_tiles = cfg.d_ff // tile
-        idx, nvalid = preds.pack_tile_indices(
-            preds.units_to_tiles(eff, tile), n_tiles)
-        f32, compact = kfd.fused_sparse_ffn(
-            h, pf["wg"] if cfg.ffn_kind == "glu" else pf["wu"], pf["wd"],
-            idx, nvalid,
-            w_up=pf["wu"] if cfg.ffn_kind == "glu" else None, unit_mask=eff,
-            activation=cfg.activation, shift=cfg.sparsity.shift, tile=tile)
-        hh = kfd.scatter_compact(compact, idx, nvalid, n_tiles)
-        f = f32.astype(h.dtype)
-    else:
-        if cfg.ffn_kind == "glu":
-            pre = cm.maybe_sparse_matmul(h, pf["wg"], cfg, dens_in)
-            hh = act_fn(pre) * cm.maybe_sparse_matmul(h, pf["wu"], cfg,
-                                                      dens_in)
+    with jax.named_scope("attention"):
+        h = post_norm(cm.apply_norm(p["ln1"], x[:, None], cfg)[:, 0], cfg)
+        q, k, v = _qkv(p["attn"], h[:, None, :], cfg, pos[:, None],
+                       stats=stats,
+                       input_density=cfg.sparsity.input_tile_density)
+        q = q.reshape(q.shape[0], g.kvp, g.group, g.head_dim)
+    with jax.named_scope("kv_write"):
+        k_pages = cm.paged_write_token(k_pages, layer, table, pos, k[:, 0],
+                                       block_size)
+        v_pages = cm.paged_write_token(v_pages, layer, table, pos, v[:, 0],
+                                       block_size)
+    with jax.named_scope("attention"):
+        kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
+        if fast_kernels:
+            from repro.kernels import paged_attention as kpa
+            o = kpa.paged_decode_attention(q, kl, vl, table, pos,
+                                           window=cfg.sliding_window)
         else:
-            hh = act_fn(cm.maybe_sparse_matmul(h, pf["wu"], cfg, dens_in))
-        # TP serving (rules.use_mesh installed): keep the hidden activation
-        # and the γ-mask composition sharded on each shard's d_ff slice;
-        # no-op (and bit-frozen lowering) single-device
-        hh = rules.constrain(hh, "dp", "model")
-        hh = hh * eff.astype(hh.dtype)
-        f = cm.maybe_sparse_matmul(hh, pf["wd"], cfg, dens_ffn)
-    act = hh != 0
-    scores = tile_activity(hh, _ffn_tile(cfg))
-    density = jnp.mean(eff.astype(jnp.float32), axis=-1)
-    x = x + f
-    return x, k_pages, v_pages, act, scores, density
+            kg = cm.paged_gather(kl, table)
+            vg = cm.paged_gather(vl, table)
+            o = cm.decode_attention(q, kg, vg, pos,
+                                    window=cfg.sliding_window)
+        a = _attn_out(p["attn"], o.reshape(o.shape[0], 1, g.hp, g.head_dim),
+                      cfg)[:, 0]
+        x = x + a
+    from repro.kernels.fused_ffn import tile_activity
+    with jax.named_scope("ffn"):
+        h = post_norm(cm.apply_norm(p["ln2"], x[:, None], cfg)[:, 0], cfg)
+        if pred is not None:
+            (f, act, scores, density, n_active,
+             n_miss) = _ffn_decode_predicted(
+                p["ffn"], h, cfg, pred, kind=pred_kind, tile=pred_tile,
+                k_tiles=k_tiles, mask=mask, refresh=refresh,
+                measure=pred_measure, shards=pred_shards,
+                fast_kernels=fast_kernels)
+            x = x + f
+            return (x, k_pages, v_pages, act, scores, density, n_active,
+                    n_miss)
+        act_fn = acts.get(cfg.activation, shift=cfg.sparsity.shift)
+        dens_in = (cfg.sparsity.input_tile_density if cfg.sparsity.enabled
+                   else 1.0)
+        dens_ffn = (cfg.sparsity.ffn_tile_density if cfg.sparsity.enabled
+                    else 1.0)
+        pf = p["ffn"]
+        eff = mask | refresh[:, None]  # refresh ⇒ all rows participate
+        if fast_kernels and dens_in >= 1.0 and dens_ffn >= 1.0:
+            # AR fast path: the γ-window eff mask IS a per-token active
+            # set, so the whole FFN runs through the fused kernel over eff's
+            # tile list at full capacity — up- AND down-projection reads of
+            # fully-masked tiles are physically skipped; masked-off units
+            # inside gathered tiles are zeroed in-kernel (unit_mask),
+            # matching hh * eff.
+            from repro.kernels import fused_decode as kfd
+            from repro.predictor import predictors as preds
+            tile = _ffn_tile(cfg)
+            n_tiles = cfg.d_ff // tile
+            idx, nvalid = preds.pack_tile_indices(
+                preds.units_to_tiles(eff, tile), n_tiles)
+            f32, compact = kfd.fused_sparse_ffn(
+                h, pf["wg"] if cfg.ffn_kind == "glu" else pf["wu"],
+                pf["wd"], idx, nvalid,
+                w_up=pf["wu"] if cfg.ffn_kind == "glu" else None,
+                unit_mask=eff, activation=cfg.activation,
+                shift=cfg.sparsity.shift, tile=tile)
+            hh = kfd.scatter_compact(compact, idx, nvalid, n_tiles)
+            f = f32.astype(h.dtype)
+        else:
+            if cfg.ffn_kind == "glu":
+                pre = cm.maybe_sparse_matmul(h, pf["wg"], cfg, dens_in)
+                hh = act_fn(pre) * cm.maybe_sparse_matmul(h, pf["wu"], cfg,
+                                                          dens_in)
+            else:
+                hh = act_fn(cm.maybe_sparse_matmul(h, pf["wu"], cfg,
+                                                   dens_in))
+            # TP serving (rules.use_mesh installed): keep the hidden
+            # activation and the γ-mask composition sharded on each shard's
+            # d_ff slice; no-op (and bit-frozen lowering) single-device
+            hh = rules.constrain(hh, "dp", "model")
+            hh = hh * eff.astype(hh.dtype)
+            f = cm.maybe_sparse_matmul(hh, pf["wd"], cfg, dens_ffn)
+        act = hh != 0
+        scores = tile_activity(hh, _ffn_tile(cfg))
+        density = jnp.mean(eff.astype(jnp.float32), axis=-1)
+        x = x + f
+        return x, k_pages, v_pages, act, scores, density
 
 
 def decode_step_paged(params, pages, table, token, pos, cfg: ModelConfig,
@@ -983,6 +1003,7 @@ def prefill_paged(params, tokens, cfg: ModelConfig, pages, blocks,
     li = None if true_len is None else true_len - 1
     logits, kv = forward(params, tokens, cfg, return_kv=True, last_index=li)
     k, v = kv  # (L, 1, s, kvp, hd)
-    kp = cm.paged_write_prefill(pages["k"], k[:, 0], blocks, block_size)
-    vp = cm.paged_write_prefill(pages["v"], v[:, 0], blocks, block_size)
+    with jax.named_scope("kv_write"):
+        kp = cm.paged_write_prefill(pages["k"], k[:, 0], blocks, block_size)
+        vp = cm.paged_write_prefill(pages["v"], v[:, 0], blocks, block_size)
     return logits[:, -1], {"k": kp, "v": vp}

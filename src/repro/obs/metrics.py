@@ -157,11 +157,13 @@ class Histogram(_Metric):
         return int(s["count"]) if s else 0
 
     def snapshot(self) -> dict:
+        # iterate a copy: the collector's callback (obs/tracing.py) can add
+        # a series from inside any allocation, this loop's included
         return {**self._meta(), "bounds": list(self.bounds),
                 "series": {k: {"buckets": list(v["buckets"]),
                                "sum": v["sum"], "count": v["count"],
                                "min": v["min"], "max": v["max"]}
-                           for k, v in self.series.items()}}
+                           for k, v in dict(self.series).items()}}
 
 
 def _bucket_index(bounds: List[float], value: float) -> int:

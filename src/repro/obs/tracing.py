@@ -8,7 +8,26 @@
   ``perf_counter`` context managers. The engine hands the trace to
   ``step_end`` together with values it ALREADY holds on the host
   (occupancy, pool use, the step's measured density from the arrays
-  ``_account()`` fetched) — observability adds zero device syncs.
+  ``_account()`` fetched, the decoding slots and their context) —
+  observability adds zero device syncs.
+
+* Profiler spans — once the engine hands the hub an annotation factory
+  (``use_annotations(jax.profiler.TraceAnnotation)``), the step opens
+  ``repro.step`` and each phase ``repro.step.<phase>`` at the same
+  ``perf_counter`` brackets that feed the registry, the serve loop opens
+  ``repro.serve.publish`` / ``repro.serve.control`` through ``span``, and
+  every garbage collection is a ``repro.gc`` span. A profiler session
+  (``/profilez``, a benchmark's trace) then shows what the host did in
+  each gap of the device's timeline. Without a session an annotation
+  costs well under a microsecond; this module never starts a session.
+
+* Host-bound time — the engine marks each blocking fetch of a device
+  result (``fetched``) and each return of a jitted call (``launched``);
+  ``repro_engine_host_bound_seconds_total`` sums fetch-to-next-launch,
+  the time the device has nothing queued, whenever the step before left
+  work pending. ``repro_engine_step_gap_seconds`` times the same loop
+  from step end to the next step's start, and ``repro_gc_pause_seconds``
+  the collector's pauses inside it.
 
 * ``RequestSpan`` — one request's lifecycle (queued → prefilled →
   decoding → finished/cancelled), driven by scheduler state transitions
@@ -29,11 +48,13 @@ must stay stdlib-only (see obs/metrics.py).
 """
 from __future__ import annotations
 
+import gc
 import time
+import weakref
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
-from typing import Callable, Deque, Dict, Optional
+from typing import Callable, ContextManager, Deque, Dict, Optional
 
 from repro.obs.metrics import Registry
 
@@ -43,24 +64,45 @@ __all__ = ["StepTrace", "RequestSpan", "EngineObs", "format_statusz",
 # engine.step() phase names, in execution order
 PHASES = ("admit", "prefill", "dispatch", "host_sync", "sample")
 
+# profiler span names: the whole step, and each phase nested inside it
+STEP_SPAN = "repro.step"
+PHASE_SPANS = {p: f"{STEP_SPAN}.{p}" for p in PHASES}
+
 _pc = time.perf_counter
+_NULL_SPAN = nullcontext()
+
+
+def _no_span(name: str) -> ContextManager:
+    """The span factory of a hub that was given no annotation factory."""
+    return _NULL_SPAN
 
 
 class StepTrace:
-    """Per-phase wall-clock accumulator for one engine step."""
-    __slots__ = ("t0", "phases")
+    """Per-phase wall-clock accumulator for one engine step; with a span
+    factory, also the step's profiler span and one span per phase."""
+    __slots__ = ("t0", "phases", "_span", "_step_span")
 
-    def __init__(self):
+    def __init__(self, span: Callable[[str], ContextManager] = _no_span):
+        self._span = span
+        self._step_span = span(STEP_SPAN)
+        self._step_span.__enter__()
         self.t0 = _pc()
         self.phases: Dict[str, float] = {}
 
     @contextmanager
     def phase(self, name: str):
-        t = _pc()
-        try:
-            yield
-        finally:
-            self.phases[name] = self.phases.get(name, 0.0) + (_pc() - t)
+        with self._span(PHASE_SPANS[name]):
+            t = _pc()
+            try:
+                yield
+            finally:
+                self.phases[name] = self.phases.get(name, 0.0) + (_pc() - t)
+
+    def close(self) -> None:
+        """End the step's profiler span (idempotent)."""
+        if self._step_span is not None:
+            self._step_span.__exit__(None, None, None)
+            self._step_span = None
 
 
 class _NullTrace:
@@ -75,6 +117,44 @@ class _NullTrace:
 
 
 _NULL_TRACE = _NullTrace()
+
+
+class _GcPauses:
+    """The process's one ``gc.callbacks`` hook: times each collection and
+    feeds the pause to every live enabled hub. Hubs are held weakly, so
+    an engine that goes away leaves nothing behind, however many a
+    process builds. Collections never overlap (the collector runs one at
+    a time, on the thread that triggered it), so one start time will do."""
+
+    def __init__(self):
+        self.hubs: "weakref.WeakSet[EngineObs]" = weakref.WeakSet()
+        self.t0 = 0.0
+        self.span: Optional[ContextManager] = None
+
+    def add(self, hub: "EngineObs") -> None:
+        self.hubs.add(hub)
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            factory = next((h.span for h in self.hubs
+                            if h.span is not _no_span), None)
+            if factory is not None:
+                self.span = factory("repro.gc")
+                self.span.__enter__()
+            self.t0 = _pc()
+            return
+        dt = _pc() - self.t0
+        if self.span is not None:
+            self.span.__exit__(None, None, None)
+            self.span = None
+        gen = str(info["generation"])
+        for hub in list(self.hubs):
+            hub.h_gc.observe(dt, generation=gen)
+
+
+_GC_PAUSES = _GcPauses()
 
 
 @dataclass
@@ -172,11 +252,26 @@ class EngineObs:
             "repro_step_ffn_density",
             "measured FFN weight-read fraction per step (mean over active "
             "slots)", unit="ratio", lo=1e-3, factor=1.26, n_buckets=25)
-        self.h_bytes = r.histogram(
-            "repro_step_ffn_bytes",
-            "modeled per-device FFN weight bytes read this step "
-            "(density x dense bytes / TP)", unit="bytes",
-            lo=1024.0, factor=4.0, n_buckets=16)
+        self.c_decode_slots = r.counter(
+            "repro_decode_slots_total",
+            "decoding slots, summed over decode steps")
+        self.c_decode_context = r.counter(
+            "repro_decode_context_tokens_total",
+            "attention context of the decoding slots, current token "
+            "included, summed over decode steps")
+        self.h_gap = r.histogram(
+            "repro_engine_step_gap_seconds",
+            "wall time from a step that left work pending to the next "
+            "step's start (serve loop)", unit="seconds")
+        self.c_host_bound = r.counter(
+            "repro_engine_host_bound_seconds_total",
+            "time from a blocking fetch of a device result to the next "
+            "launch, with work pending: the device has nothing queued",
+            unit="seconds")
+        self.h_gc = r.histogram(
+            "repro_gc_pause_seconds",
+            "garbage-collector pause, by generation", unit="seconds",
+            lo=1e-6)
         # -- request lifecycle ----------------------------------------------
         self.c_submitted = r.counter(
             "repro_requests_submitted_total", "requests accepted by submit()")
@@ -239,6 +334,12 @@ class EngineObs:
         self.g_info = r.gauge(
             "repro_engine_info",
             "static engine configuration (value is always 1)")
+        # -- profiler spans and the host-bound clock -------------------------
+        self.span: Callable[[str], ContextManager] = _no_span
+        self._t_end: Optional[float] = None    # end of a step, work pending
+        self._t_fetch: Optional[float] = None  # last fetch, work pending
+        if enabled:
+            _GC_PAUSES.add(self)
 
     @classmethod
     def disabled(cls) -> "EngineObs":
@@ -257,22 +358,55 @@ class EngineObs:
             return
         self.g_info.set(1.0, **{k: str(v) for k, v in labels.items()})
 
+    def use_annotations(self, factory: Callable[[str], ContextManager]
+                        ) -> None:
+        """Emit spans through ``factory(name)`` from now on (the engine
+        passes ``jax.profiler.TraceAnnotation``). A disabled hub keeps
+        emitting nothing."""
+        if self.enabled:
+            self.span = factory
+
     def step_start(self):
         if not self.enabled:
             return _NULL_TRACE
-        return StepTrace()
+        st = StepTrace(self.span)
+        if self._t_end is not None:
+            self.h_gap.observe(st.t0 - self._t_end)
+            self._t_end = None
+        return st
+
+    def launched(self) -> None:
+        """A jitted call returned: the device has work queued. Ends the
+        host-bound interval the last fetch began, if one is open."""
+        if self._t_fetch is not None:
+            self.c_host_bound.inc(_pc() - self._t_fetch)
+            self._t_fetch = None
+
+    def fetched(self) -> None:
+        """A blocking fetch of a device result returned: from here to the
+        next launch the device has nothing queued."""
+        if self.enabled:
+            self._t_fetch = _pc()
 
     def step_end(self, st, *, worked: bool, slots_active: int, n_slots: int,
                  queue_depth: int, pool_used: int, pool_total: int,
+                 pending: bool = False,
+                 decode_slots: int = 0, context_tokens: int = 0,
                  density: Optional[float] = None,
-                 tiles: Optional[float] = None,
-                 ffn_bytes: Optional[float] = None) -> None:
+                 tiles: Optional[float] = None) -> None:
         """Close one step trace. Gauges always update (an idle engine still
         reports its occupancy truthfully); step/phase histograms only count
-        steps that did work, so percentiles aren't diluted by idle polls."""
+        steps that did work, so percentiles aren't diluted by idle polls.
+        ``pending``: the step left work for the next one; without it, the
+        wait until the next step is an idle server's, and neither the step
+        gap nor the host-bound time counts it."""
         if not self.enabled:
             return
         t = _pc()
+        st.close()
+        self._t_end = t if pending else None
+        if not pending:
+            self._t_fetch = None
         self.g_active.set(slots_active)
         self.g_occupancy.set(slots_active / max(1, n_slots))
         self.g_queue.set(queue_depth)
@@ -288,8 +422,9 @@ class EngineObs:
                 self.h_density.observe(density)
             if tiles is not None:
                 self.h_density.observe(tiles, granularity="tile")
-            if ffn_bytes is not None:
-                self.h_bytes.observe(ffn_bytes)
+            if decode_slots:
+                self.c_decode_slots.inc(decode_slots)
+                self.c_decode_context.inc(context_tokens)
         self.self_time_s += _pc() - t
 
     def predictor_counts(self, n_active: int, n_miss: int) -> None:
@@ -459,6 +594,7 @@ class EngineObs:
         self.finished_spans.clear()
         self.self_time_s = 0.0
         self.steps = 0
+        self._t_end = self._t_fetch = None
 
 
 # ---------------------------------------------------------------------------

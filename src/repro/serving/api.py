@@ -177,7 +177,8 @@ class AsyncServingEngine:
         loop = asyncio.get_running_loop()
         try:
             while True:
-                self._apply_control()
+                with self.engine.obs.span("repro.serve.control"):
+                    self._apply_control()
                 if not self._running and not self._sessions:
                     return
                 if not self.engine.scheduler.has_work():
@@ -189,7 +190,8 @@ class AsyncServingEngine:
                     continue
                 progressed = await loop.run_in_executor(None,
                                                         self.engine.step)
-                self._publish()
+                with self.engine.obs.span("repro.serve.publish"):
+                    self._publish()
                 if not progressed and self.engine.scheduler.has_work():
                     # queue head can never be admitted (engine.drain would
                     # raise here) — fail those streams instead of spinning

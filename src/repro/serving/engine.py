@@ -162,9 +162,11 @@ class ContinuousBatchingEngine:
     obs: an ``EngineObs`` observability hub (repro.obs). None (default)
         creates an enabled one per engine: step-phase tracing, per-request
         spans, and labeled counters/histograms feed the ``/metrics`` and
-        ``/statusz`` endpoints (launch/serve_api.py). Hooks only touch
-        host-side values the step already fetched — zero added device
-        syncs, and f32 greedy streams are byte-identical with
+        ``/statusz`` endpoints (launch/serve_api.py); the step's phases
+        are also ``jax.profiler.TraceAnnotation`` spans, which a profiler
+        capture (``/profilez``) lays beside the device's ops. Hooks only
+        touch host-side values the step already fetched — zero added
+        device syncs, and f32 greedy streams are byte-identical with
         observability on or off (tests/test_obs.py). Pass
         ``EngineObs.disabled()`` to turn every hook into an early return.
     """
@@ -261,6 +263,7 @@ class ContinuousBatchingEngine:
         self.warm_masks = warm_masks
         self.prefill_budget = config.prefill_budget
         self.obs = obs if obs is not None else EngineObs()
+        self.obs.use_annotations(jax.profiler.TraceAnnotation)
         # preemption resumes a request mid-sequence via chunked prefill of
         # its prompt+generated prefix — without the chunked path the knob
         # is inert (downgraded, not an error: it defaults on)
@@ -661,11 +664,14 @@ class ContinuousBatchingEngine:
                 jnp.asarray([sp.top_k], jnp.int32),
                 jnp.asarray([sp.top_p], jnp.float32),
                 jnp.asarray(rkey[None, :]))
+            self.obs.launched()
             if self.spec:
                 self.draft_pages = self._prefill_draft(
                     self.draft_params, jt, self.draft_pages, blocks,
                     true_len)
-            sched.seed(slot, int(nxt), float(lp), step=self.t)
+            tok, tok_lp = int(nxt), float(lp)
+            self.obs.fetched()
+            sched.seed(slot, tok, tok_lp, step=self.t)
 
     def _prefill_one_chunk(self) -> None:
         """One fixed-shape chunked-prefill window step (see _admit)."""
@@ -690,11 +696,14 @@ class ContinuousBatchingEngine:
             jnp.asarray(refresh), jnp.asarray(keep), jnp.asarray(temps),
             jnp.asarray(tks), jnp.asarray(tps), jnp.asarray(skeys),
             jnp.asarray(gen))
+        self.obs.launched()
         if self.spec:
             self.draft_pages = self._prefill_chunk_draft(
                 self.draft_params, self.draft_pages, jt, jtok, jp, jc)
-        sched.record_prefill(np.asarray(nxt), np.asarray(lp), clen,
-                             warm=self.warm_masks, step=self.t)
+        nxt_np, lp_np = np.asarray(nxt), np.asarray(lp)
+        self.obs.fetched()
+        sched.record_prefill(nxt_np, lp_np, clen, warm=self.warm_masks,
+                             step=self.t)
 
     def _account(self, active, dens_np, tiles_np, act) -> None:
         """Per-(active slot, step) weight-I/O + sparsity-tracker updates.
@@ -740,19 +749,21 @@ class ContinuousBatchingEngine:
 
     def _obs_step_end(self, st, worked: bool, active) -> None:
         """Close the step's trace with host-side state only (occupancy,
-        pool, queue, and the density _account() already stashed)."""
+        pool, queue, the density _account() already stashed, and the
+        decoded slots' positions after the step)."""
         if not self.obs.enabled:
             return
         sched = self.scheduler
-        dens = self._step_density
         self.obs.step_end(
             st, worked=worked, slots_active=len(active),
             n_slots=sched.n_slots, queue_depth=len(sched.queue),
             pool_used=sched.allocator.allocated,
             pool_total=sched.allocator.n_blocks - 1,
-            density=dens, tiles=self._step_tiles,
-            ffn_bytes=(None if dens is None
-                       else dens * self._mode_ffn_bytes() / self.ffn_tp))
+            pending=bool(sched.queue) or any(
+                s is not None and not s.done for s in sched.slots),
+            decode_slots=len(active),
+            context_tokens=sum(sched.slots[i].next_pos for i in active),
+            density=self._step_density, tiles=self._step_tiles)
 
     def _advance(self, active, st) -> None:
         """Decode one token for every active slot."""
@@ -765,9 +776,11 @@ class ContinuousBatchingEngine:
                 jnp.asarray(tokens), jnp.asarray(pos), self.masks,
                 jnp.asarray(refresh), jnp.asarray(temps), jnp.asarray(tks),
                 jnp.asarray(tps), jnp.asarray(keys), jnp.asarray(gen))
+            self.obs.launched()
         with st.phase("host_sync"):
             dens_np, tiles_np = np.asarray(dens), np.asarray(tiles)
             nxt_np, lp_np = np.asarray(nxt), np.asarray(lp)
+            self.obs.fetched()
         with st.phase("sample"):
             self._account(active, dens_np, tiles_np, act)
             sched.record(nxt_np, lp_np)
@@ -787,10 +800,12 @@ class ContinuousBatchingEngine:
                 jnp.asarray(refresh), self.predictor.params,
                 jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
                 jnp.asarray(keys), jnp.asarray(gen))
+            self.obs.launched()
         with st.phase("host_sync"):
             dens_np, tiles_np = np.asarray(dens), np.asarray(tiles)
             na, nm = np.asarray(n_act), np.asarray(n_miss)
             nxt_np, lp_np = np.asarray(nxt), np.asarray(lp)
+            self.obs.fetched()
         with st.phase("sample"):
             self._account(active, dens_np, tiles_np, act)
             step_act = step_miss = 0
@@ -826,17 +841,21 @@ class ContinuousBatchingEngine:
             props, self.draft_pages = self._draft(
                 self.draft_params, self.draft_pages, jt, jnp.asarray(tokens),
                 jp, jw, jtemps, jtks, jtps, jkeys, jgen)
+            self.obs.launched()
             # the (B, γ) proposal fetch is pipeline-necessary (the verify
             # window is built from it), so it stays in "dispatch"
-            window = np.concatenate([tokens[:, None], np.asarray(props)],
-                                    axis=1)
+            props_np = np.asarray(props)
+            self.obs.fetched()
+            window = np.concatenate([tokens[:, None], props_np], axis=1)
             (target, lp, self.pages, self.masks, tiles, udens,
              act) = self._verify(
                 self.params, self.pages, jt, jnp.asarray(window), jp, jw,
                 self.masks, jtemps, jtks, jtps, jkeys, jgen)
+            self.obs.launched()
         with st.phase("host_sync"):
             udens_np, tiles_np = np.asarray(udens), np.asarray(tiles)
             target_np, lp_np = np.asarray(target), np.asarray(lp)
+            self.obs.fetched()
         with st.phase("sample"):
             self._account(active, udens_np, tiles_np, act)
             sched.record_spec(window, target_np, lp_np, wlen)

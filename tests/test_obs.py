@@ -16,10 +16,16 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import gc
+import glob
 import json
 import math
+import os
 import random
+import time
+import weakref
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -28,8 +34,9 @@ from hypothesis import strategies as hst
 from repro.configs import get_config
 from repro.launch.serve_api import ApiServer, build_engine, parse_args
 from repro.obs import (
-    EngineObs, Histogram, Registry, format_statusz, hist_quantile,
+    PHASES, EngineObs, Histogram, Registry, format_statusz, hist_quantile,
     merge_snapshots, parse_prometheus, render_prometheus, snapshot_quantile,
+    tracing,
 )
 from repro.serving import AsyncServingEngine, ContinuousBatchingEngine
 
@@ -241,6 +248,119 @@ def test_statusz_renders_for_disabled_obs():
     text = format_statusz(eng)
     assert "observability=off" in text
     assert "latency" not in text
+
+
+# -- profiler spans, host-bound time, GC pauses -----------------------------
+
+
+def _repro_spans(trace_dir):
+    """(name, start_ns, end_ns, host line) of every ``repro.*`` span in the
+    capture under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                     (plane.name, i)) for ev in line.events
+                    if ev.name.startswith("repro.")]
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_profiler_capture_nests_phases_in_each_step(tmp_path):
+    eng = _engine("plain")
+    calls = []
+    inner = eng.step
+
+    def step():
+        calls.append(1)
+        return inner()
+    eng.step = step
+    prompts = _prompts(3)
+
+    async def serve():
+        async with AsyncServingEngine(eng) as api:
+            await asyncio.gather(*(api.generate(p, 4) for p in prompts))
+
+    _serve(eng, prompts, [4, 4, 4])  # compile outside the capture
+    calls.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        asyncio.run(asyncio.wait_for(serve(), TIMEOUT_S))
+    finally:
+        jax.profiler.stop_trace()
+    spans = _repro_spans(str(tmp_path))
+    steps = [e for e in spans if e[0] == "repro.step"]
+    assert len(steps) == len(calls) > 0
+    order = {f"repro.step.{p}": i for i, p in enumerate(PHASES)}
+    seen = set()
+    for _, t0, t1, line in steps:
+        inside = [e for e in spans if e[0] in order and e[3] == line
+                  and t0 <= e[1] and e[2] <= t1]
+        ranks = [order[e[0]] for e in inside]
+        assert ranks == sorted(ranks)
+        seen.update(e[0] for e in inside)
+    assert seen == set(order)
+    # every phase span lies inside a step span
+    assert all(any(s[3] == e[3] and s[1] <= e[1] and e[2] <= s[2]
+                   for s in steps) for e in spans if e[0] in order)
+    serve_spans = [e for e in spans if e[0].startswith("repro.serve.")]
+    assert {e[0] for e in serve_spans} == {"repro.serve.control",
+                                          "repro.serve.publish"}
+    for _, a, b, _ in serve_spans:  # between steps, never inside one
+        assert not any(s[1] < b and a < s[2] for s in steps)
+
+
+def test_idle_pause_adds_no_host_bound_time():
+    eng = _engine("plain")
+    obs = eng.obs
+
+    def gap_sum():
+        return obs.h_gap.snapshot()["series"][""]["sum"]
+    _serve(eng, _prompts(2), [4, 4])  # compiles
+    _serve(eng, _prompts(2), [4, 4])
+    bound0, gaps0 = obs.c_host_bound.value(), gap_sum()
+    assert bound0 > 0 and gaps0 > 0
+    time.sleep(0.3)  # an idle server, waiting for requests
+    t0 = time.perf_counter()
+    _serve(eng, _prompts(2), [4, 4])
+    wall = time.perf_counter() - t0
+    # what the second burst counted lies inside the burst: the pause
+    # before it is in neither the host-bound time nor the step gaps
+    assert 0 < obs.c_host_bound.value() - bound0 <= wall
+    assert 0 < gap_sum() - gaps0 <= wall
+
+
+def _gc_count(obs):
+    return sum(s["count"] for s in obs.h_gc.snapshot()["series"].values())
+
+
+def test_gc_pause_is_counted_once_per_live_hub():
+    hubs = [EngineObs(), EngineObs(), EngineObs.disabled()]
+    eng = _engine("plain")
+    ref = weakref.ref(eng.obs)
+    hooks = [cb for cb in gc.callbacks if isinstance(cb, tracing._GcPauses)]
+    assert len(hooks) == 1
+    was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        before = [_gc_count(h) for h in hubs[:2]]
+        gc.collect()
+        assert [_gc_count(h) for h in hubs[:2]] == [b + 1 for b in before]
+        assert hubs[2].snapshot() == {}
+        del eng
+        gc.collect()
+        assert ref() is None
+        assert [cb for cb in gc.callbacks
+                if isinstance(cb, tracing._GcPauses)] == hooks
+    finally:
+        if was_enabled:
+            gc.enable()
 
 
 # -- metrics primitives under hypothesis -------------------------------------
