@@ -106,20 +106,20 @@ def _attn_rows():
     n_blocks, bs, nb = (9, 8, 4) if SMOKE else (17, 16, 8)
     rng = np.random.RandomState(1)
     q = jnp.asarray(rng.randn(b, W, kvp, g, hd), jnp.float32)
-    kp = jnp.asarray(rng.randn(n_blocks, kvp, bs, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(n_blocks, kvp, bs, hd), jnp.float32)
+    kp = jnp.asarray(rng.randn(1, n_blocks, kvp, bs, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(1, n_blocks, kvp, bs, hd), jnp.float32)
     table = jnp.asarray(rng.randint(1, n_blocks, (b, nb)), jnp.int32)
     pos = jnp.full((b, W), nb * bs - 1, jnp.int32)
     itemsize = 4
     cache = kpa.modeled_cache_bytes(nb, bs, kvp, hd, itemsize) * b
 
     def gathered():
-        kg = cm.paged_gather(kp, table)
-        vg = cm.paged_gather(vp, table)
+        kg = cm.paged_gather(kp[0], table)
+        vg = cm.paged_gather(vp[0], table)
         return cm.window_attention(q, kg, vg, pos, window=0)
 
     def fused():
-        return kpa.paged_window_attention(q, kp, vp, table, pos, window=0)
+        return kpa.paged_window_attention(q, kp, vp, table, pos, 0, window=0)
 
     np.testing.assert_allclose(np.asarray(fused()), np.asarray(gathered()),
                                atol=1e-5)

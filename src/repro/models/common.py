@@ -315,17 +315,23 @@ SCRATCH_BLOCK = 0
 
 
 def init_paged_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
-                     dtype=None, sharding=None) -> PyTree:
+                     dtype=None, sharding=None,
+                     head_dim: Optional[int] = None) -> PyTree:
     """Block pool, head-major within a block (decode reads it untransposed).
 
     ``sharding`` (an optional jax Sharding, e.g. NamedSharding over the
     serving mesh from rules.paged_cache_pspec) allocates the pool directly
     into its distributed layout — a production pool is sized to fill HBM
     across the mesh and must never materialize on one device first. Still
-    mesh-agnostic: the layout decision lives with the caller."""
+    mesh-agnostic: the layout decision lives with the caller.
+
+    ``head_dim`` (None = the model's) stores each K/V row that wide, the
+    model's values first and zeros after: the Pallas kernels' pool keeps
+    whole 128-lane rows (kernels/paged_attention.py ``pool_head_dim``)."""
     g = HeadGeometry(cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim)
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
-    shape = (cfg.n_layers, n_blocks, g.kvp, block_size, g.head_dim)
+    shape = (cfg.n_layers, n_blocks, g.kvp, block_size,
+             head_dim or g.head_dim)
     kw = {} if sharding is None else {"device": sharding}
     return {"k": jnp.zeros(shape, dtype, **kw),
             "v": jnp.zeros(shape, dtype, **kw)}
@@ -347,48 +353,62 @@ def paged_gather(pages_l: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
 
 def paged_write_window(pages: jnp.ndarray, layer, table: jnp.ndarray,
                        pos: jnp.ndarray, val: jnp.ndarray, block_size: int,
-                       enable: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                       enable: Optional[jnp.ndarray] = None, *,
+                       fast_kernels: bool = False) -> jnp.ndarray:
     """Scatter a W-token window's K or V per request through the block table.
 
-    pages: (L, n_blocks, kvp, bs, hd); layer: scalar (may be traced);
-    table: (b, nb); pos: (b, W) absolute write positions; val: (b, W, kvp,
-    hd); enable: (b, W) bool — window tokens past a slot's valid window
-    length (and every token of an idle slot) are routed to the scratch
-    block, so a write can NEVER land outside the blocks a request owns. A
-    true scatter — no full-layer rewrite rides the loop. Shared by the
-    speculative verify window AND chunked prefill (transformer.py
+    pages: (L, n_blocks, kvp, bs, hd), the whole stacked pool; layer: scalar
+    (may be traced); table: (b, nb); pos: (b, W) absolute write positions;
+    val: (b, W, kvp, hd); enable: (b, W) bool — window tokens past a slot's
+    valid window length (and every token of an idle slot) are routed to the
+    scratch block, so a write can NEVER land outside the blocks a request
+    owns. A true scatter — no full-layer rewrite rides the loop. Shared by
+    the speculative verify window AND chunked prefill (transformer.py
     ``prefill_chunk_paged``), whose chunks resume at arbitrary block-
     aligned positions over possibly prefix-cache-shared tables.
-    """
+
+    ``fast_kernels`` stores the rows with the aliased Pallas writer
+    (kernels/paged_attention.py ``paged_kv_write``) instead of XLA's
+    scatter: the same values at the same places (rows zero-padded to the
+    pool's width), written where they lie, without the relayout of the
+    whole pool that the scatter's preferred layout forces around a
+    program whose kernels read row-major blocks."""
     nb = table.shape[1]
     blk = jnp.take_along_axis(table, jnp.clip(pos // block_size, 0, nb - 1),
                               axis=1)  # (b, W)
     if enable is not None:
         blk = jnp.where(enable, blk, SCRATCH_BLOCK)
     off = pos % block_size
+    if fast_kernels:
+        from repro.kernels import paged_attention as kpa
+        return kpa.paged_kv_write(pages, val, layer, blk, off)
     return pages.at[layer, blk, :, off, :].set(val.astype(pages.dtype))
 
 
 def paged_write_token(pages: jnp.ndarray, layer, table: jnp.ndarray,
                       pos: jnp.ndarray, val: jnp.ndarray,
-                      block_size: int) -> jnp.ndarray:
+                      block_size: int, *,
+                      fast_kernels: bool = False) -> jnp.ndarray:
     """Scatter one token's K or V per request — ``paged_write_window`` at
     W = 1. pos: (b,); val: (b, kvp, hd)."""
     return paged_write_window(pages, layer, table, pos[:, None],
-                              val[:, None], block_size)
+                              val[:, None], block_size,
+                              fast_kernels=fast_kernels)
 
 
 def paged_write_prefill(pages: jnp.ndarray, kv: jnp.ndarray,
                         blocks: jnp.ndarray, block_size: int) -> jnp.ndarray:
     """Write a whole prompt's K or V into freshly allocated blocks.
 
-    pages: (L, n_blocks, kvp, bs, hd); kv: (L, s, kvp, hd) from prefill;
-    blocks: (nb,) with nb*bs >= s (tail zero-padded inside the last block).
+    pages: (L, n_blocks, kvp, bs, hd'); kv: (L, s, kvp, hd) from prefill,
+    hd <= hd' (rows zero-padded to the pool's width); blocks: (nb,) with
+    nb*bs >= s (tail zero-padded inside the last block).
     """
-    L, s, kvp, hd = kv.shape
+    L, s, kvp, _ = kv.shape
+    hd = pages.shape[-1]
     nb = blocks.shape[0]
     pad = nb * block_size - s
-    kv = jnp.pad(kv, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kv = jnp.pad(kv, ((0, 0), (0, pad), (0, 0), (0, hd - kv.shape[-1])))
     tiles = kv.reshape(L, nb, block_size, kvp, hd).transpose(0, 1, 3, 2, 4)
     return pages.at[:, blocks].set(tiles.astype(pages.dtype))
 

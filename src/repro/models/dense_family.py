@@ -33,8 +33,9 @@ def model_decode(params, cache, token, pos, cfg: ModelConfig, stats=None,
 # -- continuous-batching (paged-cache) serving interface --------------------
 
 def init_paged_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
-                     sharding=None):
-    return cm.init_paged_cache(cfg, n_blocks, block_size, sharding=sharding)
+                     sharding=None, head_dim=None):
+    return cm.init_paged_cache(cfg, n_blocks, block_size, sharding=sharding,
+                               head_dim=head_dim)
 
 
 def model_prefill_paged(params, batch, cfg: ModelConfig, pages, blocks,

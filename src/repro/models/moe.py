@@ -319,8 +319,9 @@ def apply_block_window_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
 
 
 def init_paged_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
-                     sharding=None):
-    return cm.init_paged_cache(cfg, n_blocks, block_size, sharding=sharding)
+                     sharding=None, head_dim=None):
+    return cm.init_paged_cache(cfg, n_blocks, block_size, sharding=sharding,
+                               head_dim=head_dim)
 
 
 def model_prefill_paged(params, batch, cfg: ModelConfig, pages, blocks,

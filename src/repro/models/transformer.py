@@ -492,10 +492,11 @@ def apply_attn_window_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
     table: (b, nb) block ids. Causal within the window: token i attends to
     cache positions <= pos[:, i]. Returns (out (b, W, d), k_pages, v_pages).
 
-    ``fast_kernels`` reads the pool THROUGH the block table inside a Pallas
-    kernel (kernels/paged_attention.py) instead of materializing the
-    ``paged_gather`` copy — same math (streams match at f32), half the
-    cache traffic.
+    ``fast_kernels`` reads the stacked pool THROUGH the block table inside
+    a Pallas kernel (kernels/paged_attention.py) instead of slicing the
+    layer and materializing the ``paged_gather`` copy, and writes the
+    window's K/V with the aliased Pallas writer — same math (streams match
+    at f32), half the cache traffic, and no pool-sized copy.
     """
     g = attn_geometry(cfg)
     b, W, _ = x.shape
@@ -504,16 +505,18 @@ def apply_attn_window_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
     q = q.reshape(b, W, g.kvp, g.group, g.head_dim)
     with jax.named_scope("kv_write"):
         k_pages = cm.paged_write_window(k_pages, layer, table, pos, k,
-                                        block_size, valid)
+                                        block_size, valid,
+                                        fast_kernels=fast_kernels)
         v_pages = cm.paged_write_window(v_pages, layer, table, pos, v,
-                                        block_size, valid)
-    kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
-    vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
+                                        block_size, valid,
+                                        fast_kernels=fast_kernels)
     if fast_kernels:
         from repro.kernels import paged_attention as kpa
-        o = kpa.paged_window_attention(q, kl, vl, table, pos,
-                                       window=cfg.sliding_window)
+        o = kpa.paged_window_attention(q, k_pages, v_pages, table, pos,
+                                       layer, window=cfg.sliding_window)
     else:
+        kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
         kg = cm.paged_gather(kl, table)
         vg = cm.paged_gather(vl, table)
         o = cm.window_attention(q, kg, vg, pos, window=cfg.sliding_window)
@@ -798,17 +801,19 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
         q = q.reshape(q.shape[0], g.kvp, g.group, g.head_dim)
     with jax.named_scope("kv_write"):
         k_pages = cm.paged_write_token(k_pages, layer, table, pos, k[:, 0],
-                                       block_size)
+                                       block_size, fast_kernels=fast_kernels)
         v_pages = cm.paged_write_token(v_pages, layer, table, pos, v[:, 0],
-                                       block_size)
+                                       block_size, fast_kernels=fast_kernels)
     with jax.named_scope("attention"):
-        kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False)
-        vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False)
         if fast_kernels:
             from repro.kernels import paged_attention as kpa
-            o = kpa.paged_decode_attention(q, kl, vl, table, pos,
-                                           window=cfg.sliding_window)
+            o = kpa.paged_decode_attention(q, k_pages, v_pages, table, pos,
+                                           layer, window=cfg.sliding_window)
         else:
+            kl = jax.lax.dynamic_index_in_dim(k_pages, layer, 0,
+                                              keepdims=False)
+            vl = jax.lax.dynamic_index_in_dim(v_pages, layer, 0,
+                                              keepdims=False)
             kg = cm.paged_gather(kl, table)
             vg = cm.paged_gather(vl, table)
             o = cm.decode_attention(q, kg, vg, pos,

@@ -275,7 +275,8 @@ class ContinuousBatchingEngine:
                                    aging_steps=config.aging_steps)
         self.pages = fam.init_paged_cache(
             cfg, n_blocks, block_size,
-            sharding=self._pool_sharding(cfg, n_blocks))
+            sharding=self._pool_sharding(cfg, n_blocks),
+            head_dim=self._pool_head_dim(cfg))
         self.masks = jnp.zeros((cfg.n_layers, n_slots, cfg.d_ff), bool,
                                **self._masks_alloc_kw(n_slots))
         self.trackers: Dict[int, AggregatedTracker] = {}
@@ -446,7 +447,8 @@ class ContinuousBatchingEngine:
             # (its layer count / head geometry differ from the target's)
             self.draft_pages = dfam.init_paged_cache(
                 draft_cfg, n_blocks, block_size,
-                sharding=self._pool_sharding(draft_cfg, n_blocks))
+                sharding=self._pool_sharding(draft_cfg, n_blocks),
+                head_dim=self._pool_head_dim(draft_cfg))
 
             def draft(dparams, dpages, table, token, pos0, wlen,
                       temps, tks, tps, keys, gen0):
@@ -552,6 +554,15 @@ class ContinuousBatchingEngine:
         shape = (cfg_.n_layers, n_blocks, g.kvp, self.block_size, g.head_dim)
         return NamedSharding(self.mesh,
                              rules.paged_cache_pspec(shape, self.mesh))
+
+    def _pool_head_dim(self, cfg_: ModelConfig) -> Optional[int]:
+        """Head width the KV pool stores: whole 128-lane tiles on the fast
+        path (kernels/paged_attention.py ``pool_head_dim``), the model's
+        own off it (None) — the frozen lowerings keep their pool."""
+        if not self.fast_kernels:
+            return None
+        from repro.kernels import paged_attention as kpa
+        return kpa.pool_head_dim(cfg_.resolved_head_dim)
 
     def _masks_alloc_kw(self, n_slots: int) -> Dict:
         """Allocation kwargs for the (L, n_slots, d_ff) γ-mask buffer:

@@ -155,25 +155,92 @@ def test_duplicated_pad_tiles_contribute_exactly_once():
 # paged attention kernel vs frozen gather-then-attend
 
 
-@pytest.mark.parametrize("W,window", [(1, 0), (5, 5)])
-def test_paged_attention_matches_gathered(W, window):
-    """In-kernel block-table gather == materializing paged_gather + the
-    frozen window_attention, for the decode (W=1) and the γ+1 verify
-    window shapes."""
+@pytest.mark.parametrize("lanes", [16, 128])
+@pytest.mark.parametrize("W,window", [(1, 0), (5, 5), (1, 5), (5, 0)])
+def test_paged_attention_matches_gathered(W, window, lanes):
+    """In-kernel block-table gather off the STACKED pool at a middle layer
+    == the same kernel on that layer's slice (bit-exact: same blocks, same
+    order) ~= materializing paged_gather + the frozen window_attention, for
+    the decode (W=1) and the γ+1 verify window shapes, with and without the
+    sliding window, on a pool as wide as the heads and on one padded to a
+    128-lane row (zeros past head_dim)."""
     b, kvp, g, hd = 3, 2, 2, 16
-    n_blocks, bs, nb = 9, 8, 4
+    L, n_blocks, bs, nb = 3, 9, 8, 4
     rng = np.random.RandomState(2)
     q = jnp.asarray(rng.randn(b, W, kvp, g, hd), jnp.float32)
-    kp = jnp.asarray(rng.randn(n_blocks, kvp, bs, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(n_blocks, kvp, bs, hd), jnp.float32)
+    pad = ((0, 0),) * 4 + ((0, lanes - hd),)
+    kp = jnp.pad(jnp.asarray(rng.randn(L, n_blocks, kvp, bs, hd),
+                             jnp.float32), pad)
+    vp = jnp.pad(jnp.asarray(rng.randn(L, n_blocks, kvp, bs, hd),
+                             jnp.float32), pad)
     table = jnp.asarray(rng.randint(1, n_blocks, (b, nb)), jnp.int32)
     pos = (jnp.asarray(rng.randint(W - 1, nb * bs, (b,)), jnp.int32)[:, None]
            + jnp.arange(-W + 1, 1, dtype=jnp.int32)[None, :])
-    kg, vg = cm.paged_gather(kp, table), cm.paged_gather(vp, table)
+    layer = 1
+    got = kpa.paged_window_attention(q, kp, vp, table, pos, jnp.int32(layer),
+                                     window=window)
+    sliced = kpa.paged_window_attention(q, kp[layer][None], vp[layer][None],
+                                        table, pos, 0, window=window)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+    kg = cm.paged_gather(kp[layer, ..., :hd], table)
+    vg = cm.paged_gather(vp[layer, ..., :hd], table)
     want = cm.window_attention(q, kg, vg, pos, window=window)
-    got = kpa.paged_window_attention(q, kp, vp, table, pos, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-6, atol=2e-6)
+
+
+def _write_case(case):
+    """(table, pos, enable) for the writer parity cases; block 0 is the
+    scratch block, bs = 16 (one bf16 row group per block)."""
+    if case == "decode_idle_slots":
+        # W = 1; slots 1 and 3 idle (all-scratch tables), so two tokens
+        # land in the scratch block's row 0
+        table = [[1, 2, 0], [0, 0, 0], [3, 4, 5], [0, 0, 0]]
+        pos = [[17], [0], [40], [0]]
+        enable = None
+    else:
+        # W = 5: slot 0 crosses from block 2 into block 3 with its last
+        # two tokens disabled; slot 1's five tokens share one block; slot 2
+        # is idle — every disabled token goes to the scratch block, several
+        # at the same row
+        table = [[1, 2, 3], [4, 5, 6], [0, 0, 0]]
+        pos = [[29, 30, 31, 32, 33], [3, 4, 5, 6, 7], [0, 1, 2, 3, 4]]
+        enable = [[1, 1, 1, 0, 0], [1, 1, 1, 1, 1], [0, 0, 0, 0, 0]]
+    table = jnp.asarray(table, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    enable = None if enable is None else jnp.asarray(enable, bool)
+    return table, pos, enable
+
+
+@pytest.mark.parametrize("lanes", [16, 128])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["decode_idle_slots", "window_disabled"])
+def test_paged_kv_write_matches_scatter(case, dtype, lanes):
+    """The aliased Pallas writer stores the same rows at the same places as
+    the frozen XLA scatter, bit for bit over the whole stacked pool:
+    disabled window tokens and idle slots in the scratch block, several
+    tokens of one slot in one block, other layers untouched; into a pool as
+    wide as the heads, or one padded to a 128-lane row (the rows' zeros
+    past head_dim written too)."""
+    table, pos, enable = _write_case(case)
+    b, W = pos.shape
+    L, n_blocks, kvp, bs, hd = 3, 7, 2, 16, 16
+    rng = np.random.RandomState(4)
+    pages = jnp.asarray(rng.randn(L, n_blocks, kvp, bs, lanes), dtype)
+    val = jnp.asarray(rng.randn(b, W, kvp, hd), dtype)
+    layer = jnp.int32(1)
+    want = cm.paged_write_window(
+        pages, layer, table, pos,
+        jnp.pad(val, ((0, 0),) * 3 + ((0, lanes - hd),)), bs, enable)
+    got = cm.paged_write_window(pages, layer, table, pos, val, bs, enable,
+                                fast_kernels=True)
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    if enable is None:  # the W = 1 entry point routes the same way
+        got1 = cm.paged_write_token(pages, layer, table, pos[:, 0],
+                                    val[:, 0], bs, fast_kernels=True)
+        np.testing.assert_array_equal(np.asarray(got1.astype(jnp.float32)),
+                                      np.asarray(want.astype(jnp.float32)))
 
 
 # ---------------------------------------------------------------------------

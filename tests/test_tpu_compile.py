@@ -13,7 +13,9 @@ let pytest-xdist workers collect different tests.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,12 +97,12 @@ def test_fused_sparse_ffn_compiles_for_v5e(one_chip, case):
 def test_paged_window_attention_compiles_for_v5e(one_chip, W):
     a = ATTN
     bf, i32 = jnp.bfloat16, jnp.int32
-    pool = (a["n_blocks"], a["kvp"], a["bs"], a["hd"])
+    pool = (2, a["n_blocks"], a["kvp"], a["bs"], a["hd"])
     shapes = [((T, W, a["kvp"], 1, a["hd"]), bf), (pool, bf), (pool, bf),
-              ((T, a["nb"]), i32), ((T, W), i32)]
+              ((T, a["nb"]), i32), ((T, W), i32), ((), i32)]
 
-    def fn(q, k, v, table, pos):
-        return kpa.paged_window_attention(q, k, v, table, pos,
+    def fn(q, k, v, table, pos, layer):
+        return kpa.paged_window_attention(q, k, v, table, pos, layer,
                                           interpret=False)
 
     text = _compile_text(fn, shapes, one_chip)
@@ -134,3 +136,104 @@ def test_sparse_up_matmul_compiles_for_v5e(one_chip):
     text = _compile_text(fn, shapes, one_chip)
     assert "tpu_custom_call" in text
     assert "sparse_up_matmul" in text
+
+
+# ---------------------------------------------------------------------------
+# whole serving programs: the KV pool is touched only in place
+
+
+STEP_SLOTS, STEP_BS, STEP_BLOCKS = 16, 64, 257  # the decode-batch cells
+
+
+def _step_cfg(width):
+    """Two layers at OPT-1.3B (head_dim 64) or OPT-6.7B (head_dim 128)
+    attention widths. The 6.7B case runs a d_model-wide FFN: at d_ff 16384
+    the layer scan's per-layer FFN weight slices alone (a separate cost)
+    outweigh one layer of the pool, the bound checked below."""
+    from repro.configs import get_config
+
+    if width == "hd64":
+        return get_config("opt-1.3b").replace(n_layers=2)
+    return get_config("opt-6.7b").replace(n_layers=2, d_ff=4096)
+
+
+def _step_program(cfg, program, sharding):
+    """(fn, argument shapes, donated index) of one jitted serving program
+    on the fast path's pool; params in bf16, as the engine serves them."""
+    from repro.models import transformer as tf
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16 if x.dtype == jnp.float32
+                      else x.dtype),
+        jax.eval_shape(lambda: tf.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = sds(_pool_shape(cfg), jnp.bfloat16)
+    pages = {"k": pool, "v": pool}
+    b, bs, i32 = STEP_SLOTS, STEP_BS, jnp.int32
+    nb = 1024 // bs
+    masks = sds((cfg.n_layers, b, cfg.d_ff), jnp.bool_)
+    refresh = sds((b,), jnp.bool_)
+    if program == "decode":
+        def fn(params, pages, table, tok, pos, masks, refresh):
+            return tf.decode_step_paged(params, pages, table, tok, pos, cfg,
+                                        masks, refresh, block_size=bs,
+                                        fast_kernels=True)
+        args = (params, pages, sds((b, nb), i32), sds((b,), i32),
+                sds((b,), i32), masks, refresh)
+        return fn, args, 1
+    if program == "window":  # γ = 4 verify; chunked prefill and the draft
+        def fn(params, pages, table, tok, pos0, wlen, masks, refresh):
+            return tf.verify_window_paged(params, pages, table, tok, pos0,
+                                          wlen, cfg, masks, refresh,
+                                          block_size=bs, fast_kernels=True)
+        args = (params, pages, sds((b, nb), i32), sds((b, 5), i32),
+                sds((b,), i32), sds((b,), i32), masks, refresh)
+        return fn, args, 1
+
+    def fn(params, tokens, pages, blocks, true_len):  # whole-prompt prefill
+        return tf.prefill_paged(params, tokens, cfg, pages, blocks,
+                                block_size=bs, true_len=true_len)
+    args = (params, sds((1, 256), i32), pages, sds((256 // bs,), i32),
+            sds((), i32))
+    return fn, args, 2
+
+
+def _pool_shape(cfg):
+    return (cfg.n_layers, STEP_BLOCKS, cfg.n_kv_heads, STEP_BS,
+            kpa.pool_head_dim(cfg.resolved_head_dim))
+
+
+@pytest.mark.parametrize("program", ["decode", "window", "prefill"])
+@pytest.mark.parametrize("width", ["hd64", "hd128"])
+def test_serving_program_touches_pool_in_place_for_v5e(one_chip, monkeypatch,
+                                                       width, program):
+    """Each program the engine runs on the fast path's pool (rows in whole
+    128-lane tiles), compiled for a v5e in the device's default layouts,
+    neither relayouts the pool nor slices a layer out of it: no copy or
+    dynamic-slice of the pool's shape or of one layer's, and less scratch
+    memory than one layer's K."""
+    # the program picks compiled kernels from the backend it runs on; the
+    # described chip is not the default backend here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _step_cfg(width)
+    fn, args, donated = _step_program(cfg, program, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(donated,)).lower(*args).compile()
+    text = compiled.as_text()
+    pool = _pool_shape(cfg)
+    layer = ",".join(map(str, pool[1:]))
+    shapes = {",".join(map(str, pool)), layer, "1," + layer}
+    moved = []
+    for m in re.finditer(r"%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                         text):
+        name, dims, op = m.groups()
+        if dims in shapes and (op in ("copy", "copy-start", "dynamic-slice",
+                                      "transpose")
+                               or "copy" in name or "dynamic-slice" in name):
+            moved.append(f"{name} {op}")
+    assert not moved, moved
+    one_layer_k = math.prod(pool[1:]) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_k
+    if program != "prefill":  # whole-prompt prefill runs no kernel
+        assert "paged_kv_write" in text and "paged_window_attention" in text
