@@ -14,7 +14,8 @@ def work(*, tokens: int, d_model: int, tile: int, tiles_named: int,
          tile_token_pairs: int, n_proj: int = 2, itemsize: int = 2):
     """tokens: decode tokens in the call; tiles_named: distinct tiles any
     token names; tile_token_pairs: (token, named tile) pairs; n_proj:
-    projections through a tile (2 for a ReLU MLP: up and down)."""
+    projections through a tile (2 for a ReLU MLP: up and down; 3 for
+    a gated FFN: gate, up and down)."""
     flops = 2.0 * tile_token_pairs * n_proj * tile * d_model
     weights = tiles_named * n_proj * tile * d_model * itemsize
     activations = 2 * tokens * d_model * itemsize

@@ -12,15 +12,13 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import gc
-import shutil
 import sys
-import tempfile
 import time
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import e2e, reference, traffic, weights
+from bench import arch, e2e, reference, traffic, weights
 from bench.spec import Spec
 
 now = time.perf_counter
@@ -78,14 +76,27 @@ class StepRecord:
 class Stepper:
     """Wraps ``engine.step``: records each step, and at the step boundaries
     after ``trace_from`` / ``trace_until`` snapshots the engine's metrics
-    registry and (with a trace directory) starts and stops the profiler,
-    so a trace holds whole steps only."""
+    registry and (with ``trace``) starts and stops the profiler, so a
+    trace holds whole steps only.
 
-    def __init__(self, engine, trace_dir: Optional[str]):
+    The profile stays in memory (``profile``, a ``ProfileData``): a
+    profiler session's ``stop_and_get_profile_data`` instead of
+    ``jax.profiler.stop_trace``, which also writes a JSON trace of every
+    event, about 110 s for the 3.5 M device ops of a 51 s ``opt-1.3b``
+    window on a TPU v5e, enough to push a traced run past its time.
+    jax's public profiler API has no stop that keeps the profile in
+    memory, so the session is jax's private ``_profiler.ProfilerSession``,
+    as written against jax 0.9.0; ``tests/bench/test_harness.py``'s
+    traced rehearsal drives it on the CPU, so a jax that moves it fails
+    there."""
+
+    def __init__(self, engine, trace: bool):
         self.engine = engine
         self.inner = engine.step
         engine.step = self.step
-        self.trace_dir = trace_dir
+        self.trace = trace
+        self.session = None
+        self.profile = None
         self.records: List[StepRecord] = []
         self.slot_of: Dict[int, int] = {}  # request uid -> slot index
         self.trace_from: Optional[float] = None
@@ -100,12 +111,12 @@ class Stepper:
         if (not self.on and not self.done and self.trace_from is not None
                 and t >= self.trace_from):
             self.snap0 = self.engine.obs.registry.snapshot()
-            if self.trace_dir:
+            if self.trace:
                 import jax
+                from jax._src.lib import _profiler
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
-                jax.profiler.start_trace(self.trace_dir,
-                                         profiler_options=opts)
+                self.session = _profiler.ProfilerSession(opts)
             self.on = True
             self.span = (now(), None)
         elif self.on and self.trace_until is not None and t >= self.trace_until:
@@ -116,9 +127,9 @@ class Stepper:
             return
         self.span = (self.span[0], now())
         self.snap1 = self.engine.obs.registry.snapshot()
-        if self.trace_dir:
-            import jax
-            jax.profiler.stop_trace()
+        if self.session is not None:
+            self.profile = self.session.stop_and_get_profile_data()
+            self.session = None
         self.on, self.done = False, True
 
     def step(self) -> bool:
@@ -127,7 +138,7 @@ class Stepper:
         before = {s.request.uid: len(s.out) for s in sched.slots
                   if s is not None}
         t0 = now()
-        if self.on and self.trace_dir:
+        if self.on and self.trace:
             import jax
             with jax.profiler.StepTraceAnnotation(
                     "engine_step", step_num=len(self.records)):
@@ -175,24 +186,19 @@ def start(chips: int) -> str:
 
 
 def program_config(mc: dict):
-    """The program's configuration for the file's model, checked against
-    every size the file states."""
+    """The program's configuration for the file's model at the file's
+    depth, checked against every field the architecture's
+    ``program_fields`` fixes."""
     from repro.configs import get_config
     cfg = get_config(mc["registry_name"]).replace(
-        n_layers=mc["num_hidden_layers"], param_dtype="bfloat16",
+        n_layers=arch.dims(mc)["n_layers"], param_dtype="bfloat16",
         compute_dtype=mc["assumed"]["serve_dtype"])
-    want = dict(d_model=mc["hidden_size"], d_ff=mc["ffn_dim"],
-                n_heads=mc["num_attention_heads"],
-                n_kv_heads=mc["num_attention_heads"],
-                vocab_size=mc["vocab_size"],
-                max_seq_len=mc["max_position_embeddings"],
-                activation=mc["activation_function"], ffn_kind="mlp",
-                norm_kind="layernorm", use_rope=False, tie_embeddings=True,
-                post_norm_relu=False, qkv_bias=False)
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want or mc["word_embed_proj_dim"] != mc["hidden_size"]:
-        raise ValueError(f"program config {got} differs from the file's "
-                         f"{want}")
+    want = arch.load(mc).program_fields(mc)
+    diff = {k: (getattr(cfg, k), v) for k, v in want.items()
+            if getattr(cfg, k) != v}
+    if diff:
+        raise ValueError(f"program config {mc['registry_name']!r} differs "
+                         f"from the file: {diff} (program, file)")
     return cfg
 
 
@@ -407,13 +413,12 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, *,
     if require_kernels and engine.fast_kernels is not True:
         raise RuntimeError("the engine resolved fast_kernels=False: the "
                            "benchmark measures the Pallas decode kernels")
-    reqs = traffic.generate(spec.mix, spec.cell, seed, seconds,
-                            mc["vocab_size"])
-    n_shapes = warm_up(engine, reqs, mc["vocab_size"])
+    vocab = arch.dims(mc)["vocab_size"]
+    reqs = traffic.generate(spec.mix, spec.cell, seed, seconds, vocab)
+    n_shapes = warm_up(engine, reqs, vocab)
     if fault is not None:
         fault(engine)
-    tdir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
-    stepper = Stepper(engine, tdir)
+    stepper = Stepper(engine, trace)
     logs, window = asyncio.run(_drive(engine, reqs, spec, seconds, stepper))
     stepper.stop()
     setup_s = window[0] - t_start
@@ -431,10 +436,9 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, *,
                 "kind": devices[0].device_kind, "count": len(devices),
                 "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))},
         trace=None)
-    if trace:
+    if stepper.profile is not None:
         from bench import trace as tr
-        out["trace"] = tr.reduce_dir(tdir, out["records"])
-        shutil.rmtree(tdir, ignore_errors=True)
+        out["trace"] = tr.reduce(stepper.profile, out["records"])
     # free the program's state before the reference runs: the pool and
     # the engine's buffers go, the benchmark's weights stay
     stepper.engine = None

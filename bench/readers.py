@@ -16,22 +16,23 @@ from bench.peaks import roofline_seconds
 def roofline(ctx, kernel: str, work_of_step) -> Optional[float]:
     """Percent of the kernel's device time that its work needs at the
     chip's peaks. ``work_of_step(step)`` gives (flops, bytes) of one call
-    in that step; each decode step makes one call per layer."""
+    in that step; each decode step makes the calls that the architecture's
+    ``dims(mc)["calls"]`` gives for the kernel."""
     tr = ctx.trace
-    if tr is None or kernel not in tr["kernels"]:
+    calls = ctx.dims["calls"].get(kernel)
+    if tr is None or kernel not in tr["kernels"] or not calls:
         return None
     n_calls, busy = tr["kernels"][kernel]
     steps = [s for s in ctx.steps if s.n_decode]
-    L = ctx.mc["num_hidden_layers"]
-    if n_calls != L * len(steps) or busy <= 0:
+    if n_calls != calls * len(steps) or busy <= 0:
         print(f"bench: {kernel}: {n_calls} calls in the trace, "
-              f"{L * len(steps)} expected from {len(steps)} decode steps;"
-              f" roofline not read", file=sys.stderr, flush=True)
+              f"{calls * len(steps)} expected from {len(steps)} decode "
+              f"steps; roofline not read", file=sys.stderr, flush=True)
         return None
     need, bounds = 0.0, set()
     for s in steps:
         t, bound = roofline_seconds(*work_of_step(s), ctx.peak)
-        need += L * t
+        need += calls * t
         bounds.add(bound)
     print(f"bench: {kernel}: {n_calls} calls, {busy:.6f} s on the device, "
           f"{need:.6f} s at the roofline, bound by {'/'.join(sorted(bounds))}",

@@ -11,7 +11,7 @@ import importlib.util
 import math
 from typing import Optional
 
-from bench import peaks
+from bench import arch, peaks
 from bench.spec import BENCH
 
 _loaded: dict = {}
@@ -43,6 +43,11 @@ class Context:
         self.steps = [r for r in raw["records"] if r.traced]
         self.span = raw["span"]
         self.trace = raw["trace"]
+
+    @property
+    def dims(self) -> dict:
+        """The model's sizes and kernel calls, from its architecture."""
+        return arch.dims(self.mc)
 
     @property
     def peak(self) -> dict:

@@ -17,6 +17,7 @@ step records. From the trace this reads:
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
 import os
@@ -90,59 +91,79 @@ def reduce(pd, records) -> Optional[dict]:
                                   (ev.start_ns + ev.duration_ns) * 1e-9,
                                   None if num is None else int(num)))
     steps.sort()
-    ops_by_dev = []
+    # an instruction's text -> (kernel or None, its name in the breakdown),
+    # or None for a control-flow op: parsed once per distinct text, since a
+    # window repeats the same few thousand instructions every step
+    kinds: Dict[str, Optional[tuple]] = {}
+
+    def kind(name: str) -> Optional[tuple]:
+        if name not in kinds:
+            k = _kernel_of(name, names)
+            kinds[name] = None if _is_container(name) else (
+                k, k or op_name(name))
+        return kinds[name]
+
+    ops_by_dev = []  # per device: (start_ns, duration_ns, kernel, name)
     for plane in devices:
-        evs = [ev for ln in plane.lines if ln.name == "XLA Ops"
-               for ev in ln.events if not _is_container(ev.name)]
+        evs = []
+        for ln in plane.lines:
+            if ln.name != "XLA Ops":
+                continue
+            for ev in ln.events:
+                kd = kind(ev.name)
+                if kd is not None:
+                    evs.append((ev.start_ns, ev.duration_ns) + kd)
         if evs:
             ops_by_dev.append(evs)
     if not ops_by_dev:
         return None
-    all_ops = [ev for evs in ops_by_dev for ev in evs]
     if steps:
         lo, hi = steps[0][0], max(s[1] for s in steps)
     else:
-        lo = min(ev.start_ns for ev in all_ops) * 1e-9
-        hi = max(ev.start_ns + ev.duration_ns for ev in all_ops) * 1e-9
+        lo = min(ev[0] for evs in ops_by_dev for ev in evs) * 1e-9
+        hi = max(ev[0] + ev[1] for evs in ops_by_dev for ev in evs) * 1e-9
     busy_each, gaps = [], []
     kernels: Dict[str, List[float]] = collections.defaultdict(
         lambda: [0, 0.0])
     op_time: Dict[str, float] = collections.Counter()
     for evs in ops_by_dev:
-        iv = _union(_clip([(ev.start_ns * 1e-9,
-                            (ev.start_ns + ev.duration_ns) * 1e-9)
-                           for ev in evs], lo, hi))
+        iv = _union(_clip([(start * 1e-9, (start + dur) * 1e-9)
+                           for start, dur, _, _ in evs], lo, hi))
         busy_each.append(sum(b - a for a, b in iv))
         edges = [lo] + [x for ab in iv for x in ab] + [hi]
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                  if edges[i + 1] > edges[i]]
-        for ev in evs:
-            t = ev.start_ns * 1e-9
-            if not lo <= t < hi:
+        for start, dur, k, op in evs:
+            if not lo <= start * 1e-9 < hi:
                 continue
-            k = _kernel_of(ev.name, names)
             if k is not None:
                 kernels[k][0] += 1
-                kernels[k][1] += ev.duration_ns * 1e-9
-            op_time[k or op_name(ev.name)] += ev.duration_ns * 1e-9
+                kernels[k][1] += dur * 1e-9
+            op_time[op] += dur * 1e-9
     n_dev = len(ops_by_dev)
+
+    # one engine runs its steps one after another: they do not overlap, so
+    # the step that holds a time is the last one that starts at or before it
+    starts = [s[0] for s in steps]
 
     def label(a: float, b: float) -> str:
         mid = (a + b) / 2
-        for s0, s1, num in steps:
-            if s0 <= mid < s1:
-                rec = records[num] if num is not None and num < len(
-                    records) else None
-                if rec is not None and rec.prefill_lens:
-                    return "engine.step (prefill + decode)"
-                return "engine.step (decode)"
+        i = bisect.bisect_right(starts, mid) - 1
+        if i >= 0 and mid < steps[i][1]:
+            num = steps[i][2]
+            rec = records[num] if num is not None and num < len(
+                records) else None
+            if rec is not None and rec.prefill_lens:
+                return "engine.step (prefill + decode)"
+            return "engine.step (decode)"
         return "between engine steps"
 
     # split each idle interval where the host changed state
     cuts = sorted({t for s0, s1, _ in steps for t in (s0, s1)})
     split = []
     for a, b in gaps:
-        pts = [a] + [c for c in cuts if a < c < b] + [b]
+        pts = ([a] + cuts[bisect.bisect_right(cuts, a):
+                          bisect.bisect_left(cuts, b)] + [b])
         split += list(zip(pts, pts[1:]))
     gaps = sorted(split, key=lambda g: g[0] - g[1])
     idle_by = collections.Counter()
@@ -158,13 +179,3 @@ def reduce(pd, records) -> Optional[dict]:
         "idle_gaps": [[label(a, b), b - a] for a, b in gaps[:TOP]],
         "idle_by_host_state": dict(idle_by),
     }
-
-
-def reduce_dir(trace_dir: str, records) -> Optional[dict]:
-    from jax.profiler import ProfileData
-    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not files:
-        return None
-    return reduce(ProfileData.from_file(max(files, key=os.path.getmtime)),
-                  records)

@@ -1,40 +1,21 @@
-"""Seeded random OPT weights, made on the device in the served dtype.
+"""Seeded random weights, made on the device in the served dtype.
 
 One jitted call draws every leaf from ``--seed``, straight into bfloat16,
-in the stacked-layer layout the serving engine takes. The plain reference
-(``bench/reference.py``) reads the same arrays: the benchmark makes them,
-not the program. Scales follow OPT's own initialisation: every projection
-and embedding N(0, init_std^2), layer-norm scale 1 and bias 0. OPT's linear
-biases are zero at initialisation, and the program has none, so neither
-side carries them.
+in the stacked-layer layout the serving engine takes. The architecture's
+module (``bench/arch/<model_type>.py``) names the leaves, their shapes and
+their fills; the plain reference reads the same arrays: the benchmark
+makes them, not the program. A leaf without a fill is drawn from
+N(0, init_std^2).
 """
 from __future__ import annotations
+
+from bench import arch
 
 VOCAB_MULTIPLE = 2048  # the engine pads the vocabulary to this multiple
 
 
-def shapes(mc: dict) -> dict:
-    """Leaf name -> (shape, fill): fill None draws N(0, init_std^2)."""
-    d, L = mc["hidden_size"], mc["num_hidden_layers"]
-    H = mc["num_attention_heads"]
-    hd, F = d // H, mc["ffn_dim"]
-    vp = -(-mc["vocab_size"] // VOCAB_MULTIPLE) * VOCAB_MULTIPLE
-    return {
-        "embed": ((vp, d), None),
-        "pos_embed": ((mc["max_position_embeddings"], d), None),
-        "final_norm/scale": ((d,), 1.0),
-        "final_norm/bias": ((d,), 0.0),
-        "layers/ln1/scale": ((L, d), 1.0),
-        "layers/ln1/bias": ((L, d), 0.0),
-        "layers/ln2/scale": ((L, d), 1.0),
-        "layers/ln2/bias": ((L, d), 0.0),
-        "layers/attn/wq": ((L, d, H, hd), None),
-        "layers/attn/wk": ((L, d, H, hd), None),
-        "layers/attn/wv": ((L, d, H, hd), None),
-        "layers/attn/wo": ((L, H, hd, d), None),
-        "layers/ffn/wu": ((L, d, F), None),
-        "layers/ffn/wd": ((L, F, d), None),
-    }
+def padded_vocab(vocab: int) -> int:
+    return -(-vocab // VOCAB_MULTIPLE) * VOCAB_MULTIPLE
 
 
 def _nest(flat: dict) -> dict:
@@ -53,9 +34,10 @@ def make(mc: dict, seed: int, dtype: str = "bfloat16"):
     import jax
     import jax.numpy as jnp
 
-    spec = shapes(mc)
+    spec = arch.load(mc).shapes(mc)
     dt = jnp.dtype(dtype)
-    vocab, std = mc["vocab_size"], mc["init_std"]
+    dm = arch.dims(mc)
+    vocab, std = dm["vocab_size"], dm["init_std"]
 
     def build(key):
         keys = jax.random.split(key, len(spec))

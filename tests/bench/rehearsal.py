@@ -1,13 +1,17 @@
-"""A tiny OPT-like cell for rehearsing the harness's host logic on the CPU.
+"""A tiny cell for rehearsing the harness's host logic on the CPU.
 
 The shapes are toy sizes: this measures nothing, it drives the same code
 paths as a chip run (weights, engine, warm-up, the asyncio window, the
 reference check) with the engine's XLA decode path in place of the
-kernels."""
+kernels. ``MC`` is a tiny OPT; another architecture's file runs through
+the same calls."""
 import json
 import time
 
-from bench import harness, spec
+import jax
+import jax.numpy as jnp
+
+from bench import arch, harness, spec
 from bench.spec import BENCH
 
 MC = dict(json.loads((BENCH / "configs" / "opt-1.3b.json").read_text()),
@@ -24,25 +28,59 @@ BACKLOG = {"arrival": "backlog", "backlog_extra": 2, "pool": 256,
 POISSON = dict(BACKLOG, arrival="poisson", warmup_s=0.5)
 
 
+def alter_tokens(engine):
+    """A token altered where it is produced: the decode step's output."""
+    inner = engine._decode
+
+    def decode(*args):
+        out = inner(*args)
+        return ((out[0] + 1) % engine.cfg.vocab_size,) + tuple(out[1:])
+    engine._decode = decode
+
+
+def keep_state(engine):
+    """A step that returns its state unchanged: the KV pool as it was."""
+    inner = engine._decode
+
+    def decode(params, pages, *rest):
+        kept = jax.tree.map(jnp.copy, pages)
+        out = inner(params, pages, *rest)
+        return out[:2] + (kept,) + tuple(out[3:])
+    engine._decode = decode
+
+
+FAULTS = [alter_tokens, keep_state]
+
+
 def tiny_cfg(mc):
+    """``harness.program_config`` without its check against the registry:
+    the registry's model with every field the architecture's module takes
+    from the file, at the file's toy sizes."""
     from repro.configs import get_config
-    return get_config("opt-1.3b").replace(
-        n_layers=mc["num_hidden_layers"], d_model=mc["hidden_size"],
-        n_heads=mc["num_attention_heads"],
-        n_kv_heads=mc["num_attention_heads"], d_ff=mc["ffn_dim"],
-        vocab_size=mc["vocab_size"],
-        max_seq_len=mc["max_position_embeddings"], param_dtype="bfloat16")
+    return get_config(mc["registry_name"]).replace(
+        n_layers=arch.dims(mc)["n_layers"], param_dtype="bfloat16",
+        **arch.load(mc).program_fields(mc))
 
 
-def tiny_spec(mix=BACKLOG, name="tiny.backlog", per_layer=()):
+def use_arch(monkeypatch, module):
+    """Let the harness find ``module`` by its ``MODEL_TYPE``, as it finds
+    ``bench/arch/<model_type>.py``."""
+    found = arch.load
+    monkeypatch.setattr(arch, "load", lambda mc: (
+        module if mc.get("model_type") == module.MODEL_TYPE else found(mc)))
+
+
+def tiny_spec(mix=BACKLOG, name="tiny.backlog", per_layer=(), mc=MC,
+              cell=CELL):
     e2e = [("output_tokens_per_s", "tokens/s"), ("setup_s", "s")]
-    return spec.Spec(name=name, chips=1, cell=dict(CELL), mc=MC, mix=mix,
+    return spec.Spec(name=name, chips=1, cell=dict(cell), mc=mc, mix=mix,
                      end_to_end=[{"name": n, "unit": u} for n, u in e2e],
                      per_layer=list(per_layer))
 
 
-def run(monkeypatch, seed, seconds=2.0, mix=BACKLOG, **kw):
+def run(monkeypatch, seed, seconds=2.0, mix=BACKLOG, mc=MC, cell=CELL,
+        trace=False, **kw):
     monkeypatch.setattr(harness, "program_config", tiny_cfg)
-    return harness.run_cell(tiny_spec(mix), seed, seconds,
-                            kw.pop("trace", False), t_start=time.perf_counter(),
+    return harness.run_cell(tiny_spec(mix, mc=mc, cell=cell), seed, seconds,
+                            trace, t_start=time.perf_counter(),
                             require_kernels=False, **kw)

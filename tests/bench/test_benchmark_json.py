@@ -7,7 +7,7 @@ import re
 
 import pytest
 
-from bench import report, spec
+from bench import arch, report, spec
 from bench.spec import BENCH, ROOT
 
 B = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -53,6 +53,8 @@ def test_config_files(c):
     assert mc["source"] == c["source"]
     assert sorted(mc["reduced"]) == sorted(c["reduced"])
     assert any(w["config"] == c["name"] for w in B["workloads"])
+    assert (BENCH / "arch" / f"{mc['model_type']}.py").is_file()
+    assert arch.load(mc).program_fields(mc)
 
 
 @pytest.mark.parametrize("m", B["end_to_end"] + B["per_layer"],

@@ -38,6 +38,28 @@ def test_attention_reads_valid_context_once():
     assert flops == 4.0 * 300 * 32 * 64
 
 
+@pytest.mark.parametrize("n_kv_heads", [32, 8, 1])
+def test_attention_reads_the_kv_heads_it_has(n_kv_heads):
+    att = report.module("counts", "paged_window_attention")
+    shape = dict(tokens=2, context_tokens=300, n_heads=32, head_dim=64)
+    mha_flops, mha_bytes = att.work(**shape)
+    flops, nbytes = att.work(**shape, n_kv_heads=n_kv_heads)
+    qo = 2 * 2 * 32 * 64 * 2
+    assert flops == mha_flops
+    assert nbytes - qo == (mha_bytes - qo) * n_kv_heads / 32
+
+
+def test_gated_ffn_reads_and_multiplies_three_projections():
+    ffn = report.module("counts", "fused_sparse_ffn")
+    shape = dict(tokens=4, d_model=4096, tile=128, tiles_named=100,
+                 tile_token_pairs=300)
+    f2, b2 = ffn.work(**shape)
+    f3, b3 = ffn.work(**shape, n_proj=3)
+    act = 2 * 4 * 4096 * 2
+    assert b3 - act == 1.5 * (b2 - act)
+    assert f3 == 1.5 * f2
+
+
 def test_peaks_table():
     pk = peaks.peaks("TPU v5 lite")
     assert pk["flops_per_s"] == 197e12 and pk["hbm_bytes_per_s"] == 819e9

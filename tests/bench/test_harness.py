@@ -5,8 +5,6 @@ import os
 import subprocess
 import sys
 
-import jax
-import jax.numpy as jnp
 import pytest
 
 import rehearsal
@@ -42,28 +40,16 @@ def test_poisson_run_reports_its_tails(monkeypatch):
     assert len(e2e.ttft_samples(raw["logs"], raw["window"])) == len(due)
 
 
-def alter_tokens(engine):
-    """A token altered where it is produced: the decode step's output."""
-    inner = engine._decode
-
-    def decode(*args):
-        out = inner(*args)
-        return ((out[0] + 1) % engine.cfg.vocab_size,) + tuple(out[1:])
-    engine._decode = decode
-
-
-def keep_state(engine):
-    """A step that returns its state unchanged: the KV pool as it was."""
-    inner = engine._decode
-
-    def decode(params, pages, *rest):
-        kept = jax.tree.map(jnp.copy, pages)
-        out = inner(params, pages, *rest)
-        return out[:2] + (kept,) + tuple(out[3:])
-    engine._decode = decode
+def test_traced_run_profiles_whole_steps(monkeypatch):
+    raw = rehearsal.run(monkeypatch, SEED, trace=True)
+    assert report.result(raw, trace=True)["correct"] is True
+    traced = [r for r in raw["records"] if r.traced]
+    assert traced and raw["span"][0] <= traced[0].t_start
+    assert traced[-1].t_end <= raw["span"][1]
+    assert raw["trace"] is None  # the CPU backend has no device plane
 
 
-@pytest.mark.parametrize("fault", [alter_tokens, keep_state])
+@pytest.mark.parametrize("fault", rehearsal.FAULTS)
 def test_fault_in_the_timed_path_is_not_correct(monkeypatch, fault):
     raw = rehearsal.run(monkeypatch, SEED, fault=fault)
     line = report.result(raw, trace=False)

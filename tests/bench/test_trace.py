@@ -76,6 +76,12 @@ OPS = [
 ]
 
 
+def opt_mc(layers):
+    return {"model_type": "opt", "num_hidden_layers": layers,
+            "hidden_size": 2048, "ffn_dim": 8192, "num_attention_heads": 32,
+            "vocab_size": 50272, "init_std": 0.02}
+
+
 def rec(prefill):
     return harness.StepRecord(0, 0, 4, 400, [64] if prefill else [], True,
                               True)
@@ -123,10 +129,8 @@ def test_top_ops_name_the_kernels(reduced):
 
 
 def test_roofline_from_trace_and_counts(reduced, capsys):
-    mc = {"num_hidden_layers": 1, "hidden_size": 2048, "ffn_dim": 8192,
-          "num_attention_heads": 32}
     ctx = report.Context.__new__(report.Context)
-    ctx.trace, ctx.mc = reduced, mc
+    ctx.trace, ctx.mc = reduced, opt_mc(1)
     ctx.steps = [rec(True), rec(False)]
     ctx.raw = {"device": {"kind": "TPU v5 lite"}}
     share = report.module("metrics", "fused_sparse_ffn_roofline").read(ctx)
@@ -139,8 +143,7 @@ def test_roofline_from_trace_and_counts(reduced, capsys):
 def test_roofline_refuses_a_call_count_it_cannot_explain(reduced):
     ctx = report.Context.__new__(report.Context)
     ctx.trace = reduced
-    ctx.mc = {"num_hidden_layers": 2, "hidden_size": 2048, "ffn_dim": 8192,
-              "num_attention_heads": 32}
+    ctx.mc = opt_mc(2)
     ctx.steps = [rec(True), rec(False)]
     ctx.raw = {"device": {"kind": "TPU v5 lite"}}
     assert report.module("metrics", "fused_sparse_ffn_roofline").read(
