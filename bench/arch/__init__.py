@@ -6,10 +6,12 @@ reads the file under the file's own key names. It gives:
 - ``dims(mc)``: ``n_layers``, ``d_model``, ``n_heads``, ``n_kv_heads``,
   ``head_dim``, ``d_ff``, ``n_proj`` (projections through an FFN tile),
   ``vocab_size``, ``init_std`` and ``calls``: each kernel's calls in one
-  decode step;
+  decode step; and, where layers attend to a window of their last keys,
+  ``windows``: one entry per layer, 0 for full attention;
 - ``program_fields(mc)``: the program's ``ModelConfig`` fields that the
   file fixes (``bench/harness.py`` checks each against the registry);
-- ``shapes(mc)``: weight leaf -> (shape, fill), for ``bench/weights.py``;
+- ``shapes(mc)``: weight leaf -> (shape, fill) or, for a leaf the program
+  pads, (shape, fill, (axis, real entries)), for ``bench/weights.py``;
 - ``logits_at(w, tokens, rows, *, mc, control)``: the plain float32
   forward pass, or its control, for ``bench/reference.py``.
 

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from bench.reference import mm
-from bench.weights import padded_vocab
+from bench.weights import padded_vocab, vocab_rows
 
 
 def dims(mc: dict) -> dict:
@@ -45,15 +45,16 @@ def program_fields(mc: dict) -> dict:
 
 
 def shapes(mc: dict) -> dict:
-    """Leaf name -> (shape, fill): fill None draws N(0, init_std^2), as
-    OPT initialises every projection and embedding; layer norms start at
-    scale 1 and bias 0."""
+    """Leaf name -> (shape, fill[, (axis, real entries)]): fill None
+    draws N(0, init_std^2), as OPT initialises every projection and
+    embedding, the embedding's padded vocabulary rows zero; layer norms
+    start at scale 1 and bias 0."""
     d, L = mc["hidden_size"], mc["num_hidden_layers"]
     H = mc["num_attention_heads"]
     hd, F = d // H, mc["ffn_dim"]
     vp = padded_vocab(mc["vocab_size"])
     return {
-        "embed": ((vp, d), None),
+        "embed": ((vp, d), None, vocab_rows(mc["vocab_size"])),
         "pos_embed": ((mc["max_position_embeddings"], d), None),
         "final_norm/scale": ((d,), 1.0),
         "final_norm/bias": ((d,), 0.0),

@@ -66,11 +66,19 @@ class CompileClock:
 class StepRecord:
     t_start: float
     t_end: float
-    n_decode: int         # slots that decoded a token this step
-    context_tokens: int   # their attention context, current token included
+    contexts: List[int]   # each slot that decoded a token this step: its
+    #                       attention context, current token included
     prefill_lens: List[int]  # prompts prefilled whole this step
     pending: bool         # work left when the step ended
     traced: bool
+
+    @property
+    def n_decode(self) -> int:
+        return len(self.contexts)
+
+    @property
+    def context_tokens(self) -> int:
+        return sum(self.contexts)
 
 
 class Stepper:
@@ -146,8 +154,7 @@ class Stepper:
         else:
             worked = self.inner()
         t1 = now()
-        n_dec = ctx = 0
-        pre = []
+        contexts, pre = [], []
         for i, s in enumerate(sched.slots):
             if s is None:
                 continue
@@ -158,9 +165,8 @@ class Stepper:
             if uid not in before:
                 pre.append(s.request.prompt_len)
             if len(s.out) > before.get(uid, 1):
-                n_dec += 1
-                ctx += s.next_pos
-        self.records.append(StepRecord(t0, t1, n_dec, ctx, pre,
+                contexts.append(s.next_pos)
+        self.records.append(StepRecord(t0, t1, contexts, pre,
                                        sched.has_work(), self.on))
         return worked
 

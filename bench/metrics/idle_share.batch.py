@@ -1,8 +1,6 @@
 """Percent of the traced window in which no operation ran on the device."""
+from bench import readers
 
 
 def read(ctx):
-    tr = ctx.trace
-    if tr is None or tr["window_s"] <= 0:
-        return None
-    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
+    return readers.idle_share(ctx)

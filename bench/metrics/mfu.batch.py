@@ -4,8 +4,4 @@ from bench import readers
 
 
 def read(ctx):
-    t0, t1 = ctx.span
-    if t0 is None or t1 is None or not ctx.steps:
-        return None
-    rate = readers.window_flops(ctx) / (t1 - t0)
-    return 100.0 * rate / ctx.peak["flops_per_s"]
+    return readers.mfu(ctx)

@@ -5,7 +5,9 @@ runs. Its file is ``gqa_glu.json`` beside this module.
 
 It enters the benchmark as ``bench/arch/<model_type>.py`` does, through
 the same four functions and with a plain float32 reference of its own,
-importing nothing of the program.
+importing nothing of the program. Its weights have the program's padded
+layout: query heads in ``bench/weights.py``'s ``head_slots``, padded
+slots and vocabulary rows zero, so the reference runs over every slot.
 """
 from __future__ import annotations
 
@@ -15,18 +17,22 @@ import jax
 import jax.numpy as jnp
 
 from bench.reference import mm
-from bench.weights import padded_vocab
+from bench.weights import head_slots, padded_vocab, vocab_rows
 
 MODEL_TYPE = "gqa_glu"
-HEAD_SLOTS = 16  # the program pads query heads to a multiple of this
+
+
+def _head_dim(mc: dict) -> int:
+    return mc.get("head_dim", mc["hidden_size"] // mc["num_attention_heads"])
 
 
 def dims(mc: dict) -> dict:
     d, H, L = (mc["hidden_size"], mc["num_attention_heads"],
                mc["num_hidden_layers"])
     return {"n_layers": L, "d_model": d, "n_heads": H,
-            "n_kv_heads": mc["num_key_value_heads"], "head_dim": d // H,
-            "d_ff": mc["intermediate_size"], "n_proj": 3,
+            "n_kv_heads": mc["num_key_value_heads"],
+            "head_dim": _head_dim(mc), "d_ff": mc["intermediate_size"],
+            "n_proj": 3,
             "vocab_size": mc["vocab_size"],
             "init_std": mc["initializer_range"],
             "calls": {"fused_sparse_ffn": L, "paged_window_attention": L}}
@@ -35,7 +41,9 @@ def dims(mc: dict) -> dict:
 def program_fields(mc: dict) -> dict:
     if mc["tie_word_embeddings"]:
         raise ValueError("this architecture has a head of its own")
-    return dict(d_model=mc["hidden_size"], d_ff=mc["intermediate_size"],
+    fields = {"head_dim": mc["head_dim"]} if "head_dim" in mc else {}
+    return dict(fields, d_model=mc["hidden_size"],
+                d_ff=mc["intermediate_size"],
                 n_heads=mc["num_attention_heads"],
                 n_kv_heads=mc["num_key_value_heads"],
                 vocab_size=mc["vocab_size"],
@@ -49,22 +57,20 @@ def program_fields(mc: dict) -> dict:
 
 def shapes(mc: dict) -> dict:
     d, L = mc["hidden_size"], mc["num_hidden_layers"]
-    H, K = mc["num_attention_heads"], mc["num_key_value_heads"]
-    if H % HEAD_SLOTS:
-        raise ValueError(f"num_attention_heads {H}: the weights would hold "
-                         f"the program's padded head slots")
-    hd, F = d // H, mc["intermediate_size"]
-    vp = padded_vocab(mc["vocab_size"])
+    hp, q_real, kvp, kv_real = head_slots(mc["num_attention_heads"],
+                                          mc["num_key_value_heads"])
+    hd, F = _head_dim(mc), mc["intermediate_size"]
+    vp, rows = padded_vocab(mc["vocab_size"]), vocab_rows(mc["vocab_size"])
     return {
-        "embed": ((vp, d), None),
-        "unembed": ((vp, d), None),
+        "embed": ((vp, d), None, rows),
+        "unembed": ((vp, d), None, rows),
         "final_norm/scale": ((d,), 1.0),
         "layers/ln1/scale": ((L, d), 1.0),
         "layers/ln2/scale": ((L, d), 1.0),
-        "layers/attn/wq": ((L, d, H, hd), None),
-        "layers/attn/wk": ((L, d, K, hd), None),
-        "layers/attn/wv": ((L, d, K, hd), None),
-        "layers/attn/wo": ((L, H, hd, d), None),
+        "layers/attn/wq": ((L, d, hp, hd), None, (2, q_real)),
+        "layers/attn/wk": ((L, d, kvp, hd), None, (2, kv_real)),
+        "layers/attn/wv": ((L, d, kvp, hd), None, (2, kv_real)),
+        "layers/attn/wo": ((L, hp, hd, d), None, (1, q_real)),
         "layers/ffn/wg": ((L, d, F), None),
         "layers/ffn/wu": ((L, d, F), None),
         "layers/ffn/wd": ((L, F, d), None),
@@ -93,7 +99,7 @@ def _rope(x, theta):
 def _layer(x, lw, *, eps, theta, control):
     lw = jax.tree.map(lambda a: a.astype(jnp.float32), lw)
     S = x.shape[0]
-    H, hd = lw["attn"]["wq"].shape[1:]
+    H, hd = lw["attn"]["wq"].shape[1:]  # every slot: padded ones are zero
     K = lw["attn"]["wk"].shape[1]
     h = _rms(x, lw["ln1"]["scale"], eps)
     q = _rope(mm("sd,dhe->she", h, lw["attn"]["wq"], control, -1, 0), theta)

@@ -79,8 +79,10 @@ def tiny_spec(mix=BACKLOG, name="tiny.backlog", per_layer=(), mc=MC,
 
 
 def run(monkeypatch, seed, seconds=2.0, mix=BACKLOG, mc=MC, cell=CELL,
-        trace=False, **kw):
+        trace=False, per_layer=(), **kw):
     monkeypatch.setattr(harness, "program_config", tiny_cfg)
-    return harness.run_cell(tiny_spec(mix, mc=mc, cell=cell), seed, seconds,
-                            trace, t_start=time.perf_counter(),
+    return harness.run_cell(tiny_spec(mix, mc=mc, cell=cell,
+                                      per_layer=per_layer),
+                            seed, seconds, trace,
+                            t_start=time.perf_counter(),
                             require_kernels=False, **kw)

@@ -8,8 +8,10 @@ logits. A second architecture, a test-only module under ``tests/bench``
 ``harness.run_cell`` by its own files alone. A file that names no
 architecture, or one that the program's registry contradicts, is refused.
 """
+import hashlib
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,6 +29,11 @@ GQA = json.loads((HERE / "gqa_glu.json").read_text())
 # (mean gap 0 to 5.75e-5: its bf16 RoPE and gate flip near-tied argmaxes
 # more often than OPT's block) and of the float8 control (8.9e-4 to 2.6e-3)
 GQA_CELL = dict(rehearsal.CELL, mean_logit_gap_limit=2.5e-4)
+# 12 query heads over 4 KV heads, 16 wide: the program pads each KV head's
+# group of 3 query heads to 4 slots, so slot 3 of every group holds no head.
+# GQA_CELL's limit holds for it: on the CPU, 12 seeds of the program read a
+# mean gap of 3.1e-6 to 6.5e-5, of the float8 control 1.3e-3 to 3.2e-3
+PADDED = dict(GQA, num_attention_heads=12, head_dim=16)
 SEED = 2**31 + 303
 
 OPT13 = {
@@ -129,19 +136,35 @@ LOGITS = {
 
 
 GOLDEN = {"opt-1.3b": OPT13, "opt-6.7b-16l": OPT67}
-STEPS = [harness.StepRecord(0, 0, n, c, [], True, True)
-         for n, c in ((16, 5123), (3, 77), (1, 1))]
+# decoding slots' contexts of three steps: 16 slots of 5123 keys in all,
+# 3 of 77, 1 of 1
+CONTEXTS = [[320] * 15 + [323], [25, 26, 26], [1]]
+STEPS = [harness.StepRecord(0, 0, c, [], True, True) for c in CONTEXTS]
+# the parent's OPT weights for rehearsal.MC: sha256 over every leaf's path
+# and bytes, in path order
+WEIGHTS_SHA256 = {
+    2**31 + 5:
+        "9a7822552bc21dcad039f86785d5813737ce7126675a2f1243bc75a8fb34bfef",
+    2**33 + 17:
+        "33db667cba89c55bb905c61793eae8fb60631e5a73063096a386790f31d85749",
+}
 
 
 @pytest.mark.parametrize("name", sorted(GOLDEN))
 def test_opt_shapes_and_flops_are_the_parents(name):
     mc, want = CONFIGS[name], GOLDEN[name]
-    got = [(k, tuple(s), f) for k, (s, f) in arch.load(mc).shapes(mc).items()]
+    spec = arch.load(mc).shapes(mc)
+    got = [(k, tuple(v[0]), v[1]) for k, v in spec.items()]
     assert got == want["shapes"]
+    # only the embedding is padded: its rows past the vocabulary
+    assert [k for k, v in spec.items() if len(v) > 2] == ["embed"]
+    axis, real = spec["embed"][2]
+    assert axis == 0 and real.sum() == mc["vocab_size"] and real[
+        :mc["vocab_size"]].all()
     assert flops.weight_macs(mc) == want["weight_macs"]
-    assert [flops.attn_macs(mc, c) for c in (1, 300, 9000.5)] == (
+    assert [flops.attn_macs(mc, [c]) for c in (1, 300, 9000.5)] == (
         want["attn_macs"])
-    assert [flops.decode_flops(mc, t, c) for t, c in ((1, 100), (16, 5123))
+    assert [flops.decode_flops(mc, c) for c in ([100], CONTEXTS[0])
             ] == want["decode_flops"]
     assert [flops.prefill_flops(mc, s) for s in (1, 135, 512)] == (
         want["prefill_flops"])
@@ -153,11 +176,64 @@ def test_opt_shapes_and_flops_are_the_parents(name):
 def test_opt_roofline_work_is_the_parents(monkeypatch, name, kernel):
     ctx = report.Context.__new__(report.Context)
     ctx.mc, ctx.steps = CONFIGS[name], STEPS
-    monkeypatch.setattr(readers, "roofline", lambda ctx, k, work: (
-        k, [work(s) for s in ctx.steps]))
+    monkeypatch.setattr(readers, "roofline", lambda ctx, k, calls: (
+        k, [calls(s) for s in ctx.steps]))
     got = report.module("metrics", f"{kernel}_roofline").read(ctx)
-    assert got == (kernel, GOLDEN[name][kernel])
-    assert ctx.dims["calls"][kernel] == CONFIGS[name]["num_hidden_layers"]
+    layers = CONFIGS[name]["num_hidden_layers"]
+    # every layer's call does the parent's work of one call
+    assert got == (kernel, [[w] * layers for w in GOLDEN[name][kernel]])
+    assert ctx.dims["calls"][kernel] == layers
+
+
+@pytest.mark.parametrize("seed", sorted(WEIGHTS_SHA256))
+def test_opt_weights_are_the_parents(seed):
+    w = weights.make(rehearsal.MC, seed)
+    h = hashlib.sha256()
+    for path, x in sorted(jax.tree_util.tree_flatten_with_path(w)[0],
+                          key=lambda t: jax.tree_util.keystr(t[0])):
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(x).tobytes())
+    assert h.hexdigest() == WEIGHTS_SHA256[seed]
+
+
+def test_windowed_layers_count_their_window(monkeypatch):
+    # four layers of 2 heads of 8; the first attends fully, the other three
+    # to their last 4 keys
+    dm = {"n_layers": 4, "d_model": 16, "n_heads": 2, "n_kv_heads": 1,
+          "head_dim": 8, "d_ff": 32, "n_proj": 3, "vocab_size": 64,
+          "calls": {"fused_sparse_ffn": 4, "paged_window_attention": 4},
+          "windows": [0, 4, 4, 4]}
+    monkeypatch.setattr(arch, "dims", lambda mc: dm)
+    per_key = 2 * 2 * 8  # score and value MACs of one key, both heads
+    # decode: slots of 2 and 10 keys read 2 + 10 fully, 2 + 4 windowed
+    assert flops.keys_per_layer({}, [2, 10]) == [12, 6, 6, 6]
+    assert flops.attn_macs({}, [2, 10]) == per_key * (12 + 3 * 6)
+    assert flops.attn_macs({}, [10]) == per_key * (10 + 3 * 4)
+    weights_macs = 4 * (2 * 16 * 2 * 8 + 2 * 16 * 1 * 8 + 3 * 16 * 32)
+    assert flops.decode_flops({}, [2, 10]) == 2 * (
+        2 * (weights_macs + 16 * 64) + per_key * 30)
+    # prefill of 6 positions: 1+2+...+6 = 21 keys fully, 1+2+3+4+4+4 = 18
+    # in a window of 4; a window as long as the prompt reads all 21
+    assert flops.prefill_flops({}, 6) == 2 * (
+        6 * weights_macs + per_key * (21 + 3 * 18) + 16 * 64)
+    # each layer's attention call reads its own window's keys
+    ctx = report.Context.__new__(report.Context)
+    ctx.mc, ctx.steps = {}, [harness.StepRecord(0, 0, [2, 10], [], True,
+                                                True)]
+    monkeypatch.setattr(readers, "roofline", lambda ctx, k, calls: [
+        calls(s) for s in ctx.steps])
+    att = report.module("counts", "paged_window_attention")
+    got = report.module("metrics", "paged_window_attention_roofline").read(
+        ctx)
+    assert got == [[att.work(tokens=2, context_tokens=k, n_heads=2,
+                             head_dim=8, n_kv_heads=1)
+                    for k in (12, 6, 6, 6)]]
+    dm["windows"] = [0, 6, 6, 6]
+    assert flops.prefill_flops({}, 6) == 2 * (
+        6 * weights_macs + per_key * 4 * 21 + 16 * 64)
+    dm["windows"] = [0, 6, 6]  # one short of the layers
+    with pytest.raises(ValueError, match="3 windows for 4 layers"):
+        flops.prefill_flops({}, 6)
 
 
 @pytest.mark.parametrize("control", [False, True])
@@ -197,7 +273,7 @@ def test_second_architecture_flops_follow_its_dims(monkeypatch):
     rehearsal.use_arch(monkeypatch, arch_gqa_glu)
     assert flops.weight_macs(GQA) == 2 * (2 * 128 * 128 + 2 * 128 * 32
                                           + 3 * 128 * 256)
-    assert flops.attn_macs(GQA, 10) == 2 * 2.0 * 128 * 10
+    assert flops.attn_macs(GQA, [10]) == 2 * 2.0 * 128 * 10
 
 
 @pytest.mark.parametrize("fault", rehearsal.FAULTS)
@@ -205,6 +281,52 @@ def test_second_architecture_fault_is_not_correct(monkeypatch, fault):
     rehearsal.use_arch(monkeypatch, arch_gqa_glu)
     raw = rehearsal.run(monkeypatch, SEED, mc=GQA, cell=GQA_CELL,
                         fault=fault)
+    assert report.result(raw, trace=False)["correct"] is False
+
+
+def test_padded_heads_are_zero_in_the_programs_slots(monkeypatch):
+    rehearsal.use_arch(monkeypatch, arch_gqa_glu)
+    w = weights.make(PADDED, SEED)
+    att = w["layers"]["attn"]
+    wq = np.asarray(att["wq"], np.float32)
+    wo = np.asarray(att["wo"], np.float32)
+    pad = np.arange(16) % 4 == 3  # slot 3 of each KV head's group of 4
+    assert wq.shape[2] == wo.shape[1] == 16
+    assert not wq[:, :, pad].any() and not wo[:, pad].any()
+    assert (np.abs(wq[:, :, ~pad]).max(axis=(0, 1, 3)) > 0).all()
+    assert (np.abs(wo[:, ~pad]).max(axis=(0, 2, 3)) > 0).all()
+    for leaf in ("embed", "unembed"):
+        rows = np.asarray(w[leaf], np.float32)
+        assert not rows[PADDED["vocab_size"]:].any()
+        assert np.abs(rows[:PADDED["vocab_size"]]).min(axis=1).any()
+
+
+def test_padded_heads_run_correct(monkeypatch):
+    rehearsal.use_arch(monkeypatch, arch_gqa_glu)
+    raw = rehearsal.run(monkeypatch, SEED, mc=PADDED, cell=GQA_CELL,
+                        control=True)
+    assert report.result(raw, trace=False)["correct"] is True
+    assert raw["check"]["tokens_checked"] >= harness.CHECK_TOKENS
+    limit = GQA_CELL["mean_logit_gap_limit"]
+    assert raw["check"]["control_mean_logit_gap"] > limit
+
+
+@pytest.mark.parametrize("fault", rehearsal.FAULTS)
+def test_padded_heads_fault_is_not_correct(monkeypatch, fault):
+    rehearsal.use_arch(monkeypatch, arch_gqa_glu)
+    raw = rehearsal.run(monkeypatch, SEED, mc=PADDED, cell=GQA_CELL,
+                        fault=fault)
+    assert report.result(raw, trace=False)["correct"] is False
+
+
+def test_padding_in_other_slots_is_not_correct(monkeypatch):
+    """Weights padded in the last four slots ("the first 12 heads are
+    real") are not the layout the program masks: the reference then
+    computes another model, and the run is not correct."""
+    rehearsal.use_arch(monkeypatch, arch_gqa_glu)
+    monkeypatch.setattr(arch_gqa_glu, "head_slots", lambda h, k: (
+        16, np.arange(16) < h, k, np.ones(k, bool)))
+    raw = rehearsal.run(monkeypatch, SEED, mc=PADDED, cell=GQA_CELL)
     assert report.result(raw, trace=False)["correct"] is False
 
 

@@ -85,7 +85,7 @@ def test_model_flops_per_token():
     from bench import flops
     # weight MACs of OPT-1.3B: 24 x (4 d^2 + 2 d F) = 1.2 G
     assert flops.weight_macs(OPT13) == 24 * (4 * 2048**2 + 2 * 2048 * 8192)
-    one = flops.decode_flops(OPT13, 1, 100)
+    one = flops.decode_flops(OPT13, [100])
     assert one == 2 * (flops.weight_macs(OPT13) + 2048 * 50272
                        + 24 * 2 * 2048 * 100)
     assert flops.prefill_flops(OPT13, 1) == 2 * (

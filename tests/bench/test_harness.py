@@ -57,6 +57,15 @@ def test_fault_in_the_timed_path_is_not_correct(monkeypatch, fault):
     assert line["compared"]["mean_logit_gap"]["value"] > LIMIT
 
 
+@pytest.mark.parametrize("fault", rehearsal.FAULTS)
+def test_poisson_fault_is_not_correct(monkeypatch, fault):
+    raw = rehearsal.run(monkeypatch, SEED, mix=rehearsal.POISSON,
+                        fault=fault)
+    line = report.result(raw, trace=False)
+    assert line["correct"] is False
+    assert line["compared"]["mean_logit_gap"]["value"] > LIMIT
+
+
 def test_refuses_an_engine_without_the_kernels(monkeypatch):
     monkeypatch.setattr(harness, "program_config", rehearsal.tiny_cfg)
     with pytest.raises(RuntimeError, match="fast_kernels"):

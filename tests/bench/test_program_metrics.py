@@ -36,9 +36,12 @@ def _hist(total, count, **labels):
     return {label_str(labels): {"sum": total, "count": count}}
 
 
-def _snap(host_bound, gap_sum, gap_n, gc0, gc2, decode_steps):
+def _snap(host_bound, gap_sum, gap_n, gc0, gc2, decode_steps, prefill_s,
+          prefill_tokens):
     return {
-        PHASE: {"series": _hist(1.0, decode_steps, phase="dispatch")},
+        PHASE: {"series": {**_hist(1.0, decode_steps, phase="dispatch"),
+                           **_hist(prefill_s, 7, phase="prefill")}},
+        "repro_prefill_tokens_total": {"series": {"": prefill_tokens}},
         "repro_engine_host_bound_seconds_total": {"series": {"": host_bound}},
         "repro_engine_step_gap_seconds": {"series": _hist(gap_sum, gap_n)},
         "repro_gc_pause_seconds": {"series": {
@@ -53,11 +56,12 @@ def _ctx(snaps):
     return ctx
 
 
-S0 = _snap(0.5, 0.2, 10, 0.01, 0.0, 100)
-S1 = _snap(0.9, 0.26, 30, 0.03, 0.05, 120)
+S0 = _snap(0.5, 0.2, 10, 0.01, 0.0, 100, 1.5, 24000)
+S1 = _snap(0.9, 0.26, 30, 0.03, 0.05, 120, 2.1, 36000)
 WANT = {"host_bound_ms": 0.4 / 20 * 1e3,     # per decode step
         "step_gap_ms": 0.06 / 20 * 1e3,      # per gap
-        "gc_pause_ms": 0.07 / 20 * 1e3}      # all generations, per step
+        "gc_pause_ms": 0.07 / 20 * 1e3,      # all generations, per step
+        "prefill_ms_per_ktok": 0.6 / 12 * 1e3}  # per 1000 prompt tokens
 
 
 @pytest.mark.parametrize("name", sorted(WANT))

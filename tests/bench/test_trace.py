@@ -83,8 +83,8 @@ def opt_mc(layers):
 
 
 def rec(prefill):
-    return harness.StepRecord(0, 0, 4, 400, [64] if prefill else [], True,
-                              True)
+    return harness.StepRecord(0, 0, [100] * 4, [64] if prefill else [],
+                              True, True)
 
 
 @pytest.fixture

@@ -11,19 +11,7 @@ from bench.spec import BENCH
 SEED = 2**31 + 12345
 
 
-# an open-loop mix of long prompts and short answers, as a later cell's
-# data file would state it
-POISSON = {"arrival": "poisson", "warmup_s": 3.0, "schedule_seed": 0,
-           "prompt": {"median": 1200, "sigma": 0.35, "min": 768,
-                      "max": 1920},
-           "output": {"median": 32, "sigma": 0.7, "min": 8, "max": 128},
-           "sampled_share": 0.25,
-           "sampling": {"temperature": 0.8, "top_p": 0.95}}
-
-
 def mix(name):
-    if name == "long-prompt-poisson":
-        return dict(POISSON)
     return json.loads((BENCH / "traffic" / f"{name}.json").read_text())
 
 
