@@ -16,8 +16,10 @@ Two entry points:
                    ``weight_io_bytes_per_step()`` (telemetry recorded
                    in-graph while serving real requests);
         modeled  — the fused kernel's BlockSpec geometry
-                   (``fused_decode.modeled_weight_bytes``: gathered tiles x
-                   projections x tile footprint) at the measured density;
+                   (``fused_decode.modeled_weight_bytes``: union tiles x
+                   projections x tile footprint) for a call of one token,
+                   whose union is its own tile list, at the measured
+                   density;
         hlo      — trip-count-scaled down-projection dot reads counted in
                    the FROZEN XLA decode step's compiled HLO
                    (``hlo_cost.CostModel.dot_weight_bytes``), the
@@ -100,13 +102,15 @@ def table(recs: List[Dict], md: bool = False) -> str:
 
 
 def kernel_bytes_per_step(engine) -> float:
-    """Per-device FFN weight HBM bytes one decode step reads through the
-    fused kernel path, modeled PURELY from the kernel's BlockSpec geometry
-    (``fused_decode.modeled_weight_bytes``) at the engine's measured
-    density: mean gathered tiles/step x projections touching each tile x
-    the (tile x d_model) tile footprint x layers, split by the FFN's
-    effective TP. Independent of the engine's own accounting — the gate
-    compares the two."""
+    """Per-device FFN weight HBM bytes one token's decode step reads
+    through the fused kernel path, modeled PURELY from the kernel's
+    BlockSpec geometry (``fused_decode.modeled_weight_bytes``) at the
+    engine's measured density: a one-token call's union is its own list,
+    so mean gathered tiles/step x projections touching each tile x the
+    (tile x d_model) tile footprint x layers, split by the FFN's effective
+    TP. A batched call reads the union of its tokens' lists once, at most
+    this figure times the tokens. Independent of the engine's own
+    accounting — the gate compares the two per-token figures."""
     import jax.numpy as jnp
 
     from repro.kernels import fused_decode as kfd

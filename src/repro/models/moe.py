@@ -380,10 +380,12 @@ def model_decode_paged(params, pages, table, token, pos, cfg: ModelConfig,
     dense family (whose decode keeps a hand-specialized bf16-frozen
     lowering), MoE serves at f32-pinned exactness from day one, so the
     window path with wlen == 1 IS the decode step — aux drops the window's
-    union_density to match the engine's 3-tuple decode contract."""
+    union_density and, running no fused FFN kernel, reports no tiles
+    fetched, to match the engine's decode contract."""
     logits, pages, new_masks, (act, scores, density, _udens) = \
         model_verify_window_paged(params, pages, table, token[:, None], pos,
                                   jnp.ones_like(pos), cfg, ffn_masks,
                                   refresh, block_size,
                                   fast_kernels=fast_kernels)
-    return logits[:, 0], pages, new_masks, (act, scores, density)
+    ffn_tiles = sp.no_ffn_tiles(token.shape[0], cfg.n_layers)
+    return logits[:, 0], pages, new_masks, (act, scores, density, ffn_tiles)

@@ -34,7 +34,7 @@ Family hooks with defaults (resolved here so the engine itself contains no
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -116,6 +116,14 @@ def refresh_union_masks(ffn_masks, act, refresh):
     return rules.constrain(
         jnp.where(refresh[None, :, None], act, ffn_masks),
         None, "dp", "model")
+
+
+def no_ffn_tiles(b: int, n_layers: Optional[int] = None):
+    """The decode aux's ``ffn_tiles`` where no fused FFN kernel ran: no
+    tiles named by any of the ``b`` slots (b,) and none fetched (). With
+    ``n_layers``, stacked on a leading layer axis like a whole step's."""
+    lead = () if n_layers is None else (n_layers,)
+    return (jnp.zeros(lead + (b,), jnp.int32), jnp.zeros(lead, jnp.int32))
 
 
 def scan_layers_paged(params, pages, cfg, x, layer_fn: Callable,

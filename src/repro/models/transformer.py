@@ -686,7 +686,8 @@ def prefill_chunk_paged(params, pages, table, tokens, pos0, clen,
 def _ffn_decode_predicted(pf, h, cfg: ModelConfig, pred_l, *, kind: str,
                           tile: int, k_tiles: int, mask, refresh,
                           measure: bool = True, shards: int = 1,
-                          fast_kernels: bool = False):
+                          fast_kernels: bool = False, ffn_stack=None,
+                          layer=None):
     """Predictor-gathered decode FFN (predictor serving mode): the
     activity predictor (repro.predictor) names each token's active tiles
     BEFORE any FFN weight is read, and both the up- and down-projections
@@ -707,6 +708,10 @@ def _ffn_decode_predicted(pf, h, cfg: ModelConfig, pred_l, *, kind: str,
     probe (n_active/n_miss come back 0), making the gathered reads the
     ONLY FFN weight traffic — the production-serving configuration.
 
+    ``ffn_stack`` / ``layer``: with fast kernels, the scan's whole FFN
+    weight stacks and this layer's index, which the fused kernel reads
+    its tiles from in place (``pf`` is this layer's slice of them).
+
     ``shards`` (the engine passes its mesh's TP degree; 1 = today's
     single-device lowering, bit-frozen) makes the packed tile lists
     model-axis-local: each TP shard packs its own capacity from its local
@@ -717,7 +722,9 @@ def _ffn_decode_predicted(pf, h, cfg: ModelConfig, pred_l, *, kind: str,
 
     Returns (f (B, d), act (B, F), scores (B, F // _ffn_tile),
              density (B,) fraction of weight tiles READ (up AND down),
-             n_active (B,), n_miss (B,))."""
+             n_active (B,), n_miss (B,), ffn_tiles) — ``ffn_tiles`` is
+             ``no_ffn_tiles`` off the fused kernel, else its
+             ``fused_decode.tile_counts``."""
     from repro.kernels import sparse_matmul as ksm
     from repro.kernels.fused_ffn import tile_activity
     from repro.predictor import predictors as preds
@@ -734,18 +741,20 @@ def _ffn_decode_predicted(pf, h, cfg: ModelConfig, pred_l, *, kind: str,
         preds.covered_tiles(idx, nvalid, n_tiles), tile)  # (B, F)
 
     gate_w = pf["wg"] if cfg.ffn_kind == "glu" else pf["wu"]
+    ffn_tiles = sp.no_ffn_tiles(h.shape[0])
     if fast_kernels:
-        # fused gather-up -> act -> scatter-down: one pass over the tile
-        # list, same per-tile dots / accumulation order as the unfused pair
-        # below (bit-equal — tests/test_fused_decode.py). cov_units is all
-        # ones inside every gathered tile, so the in-kernel activation needs
-        # no covered-mask; non-gathered tiles are exact zeros by omission.
+        # fused gather-up -> act -> scatter-down: one DMA per tile of the
+        # batch's union, each token's tiles summed in the same order as the
+        # unfused pair below (bit-equal — tests/test_fused_decode.py). The
+        # kernel multiplies each token's rows by its membership of the
+        # tile (cov_units), so tiles other tokens named stay exact zeros.
         from repro.kernels import fused_decode as kfd
-        f32, compact = kfd.fused_sparse_ffn(
-            h, gate_w, pf["wd"], idx, nvalid,
-            w_up=pf["wu"] if cfg.ffn_kind == "glu" else None,
+        glu = cfg.ffn_kind == "glu"
+        f32, hh = kfd.fused_sparse_ffn(
+            h, ffn_stack["wg" if glu else "wu"], ffn_stack["wd"], idx,
+            nvalid, w_up=ffn_stack["wu"] if glu else None, layer=layer,
             activation=cfg.activation, shift=cfg.sparsity.shift, tile=tile)
-        hh = kfd.scatter_compact(compact, idx, nvalid, n_tiles)
+        ffn_tiles = kfd.tile_counts(idx, nvalid, n_tiles)
         f = f32.astype(h.dtype)
     else:
         pre = ksm.sparse_up_matmul(h, gate_w, idx, nvalid, tile=tile)
@@ -769,7 +778,7 @@ def _ffn_decode_predicted(pf, h, cfg: ModelConfig, pred_l, *, kind: str,
     else:
         n_active = jnp.zeros(h.shape[0], jnp.int32)
         n_miss = jnp.zeros(h.shape[0], jnp.int32)
-    return f, act, scores, density, n_active, n_miss
+    return f, act, scores, density, n_active, n_miss, ffn_tiles
 
 
 def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
@@ -777,7 +786,7 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
                              pred=None, pred_kind: Optional[str] = None,
                              pred_tile: int = 128, k_tiles: int = 0,
                              pred_measure: bool = True, pred_shards: int = 1,
-                             fast_kernels: bool = False):
+                             fast_kernels: bool = False, ffn_stack=None):
     """Single-token specialization of ``apply_block_window_paged``.
 
     Mathematically the W = 1 case, but kept as its own lowering: the decode
@@ -789,7 +798,14 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
     ``pred`` (a per-layer predictor-param slice; None = off, identical
     trace to before) switches the FFN to the predictor-gathered path
     (``_ffn_decode_predicted``), which appends (n_active, n_miss) recall
-    telemetry to the return tuple.
+    telemetry to the return tuple. Either way the tuple ends with
+    ``ffn_tiles``: (tiles each slot named (B,), tiles fetched ()) through
+    the fused kernel, zeros where the layer did not run it.
+
+    ``ffn_stack``: with ``fast_kernels``, the layer scan's whole FFN weight
+    stacks (``_ffn_stack``); the fused kernel reads layer ``layer``'s tiles
+    from them in place, where a kernel operand sliced from ``p`` would
+    make XLA copy the layer's weights first.
     """
     stats = cm.StatsCollector(False)
     g = attn_geometry(cfg)
@@ -825,15 +841,15 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
     with jax.named_scope("ffn"):
         h = post_norm(cm.apply_norm(p["ln2"], x[:, None], cfg)[:, 0], cfg)
         if pred is not None:
-            (f, act, scores, density, n_active,
-             n_miss) = _ffn_decode_predicted(
+            (f, act, scores, density, n_active, n_miss,
+             ffn_tiles) = _ffn_decode_predicted(
                 p["ffn"], h, cfg, pred, kind=pred_kind, tile=pred_tile,
                 k_tiles=k_tiles, mask=mask, refresh=refresh,
                 measure=pred_measure, shards=pred_shards,
-                fast_kernels=fast_kernels)
+                fast_kernels=fast_kernels, ffn_stack=ffn_stack, layer=layer)
             x = x + f
             return (x, k_pages, v_pages, act, scores, density, n_active,
-                    n_miss)
+                    n_miss, ffn_tiles)
         act_fn = acts.get(cfg.activation, shift=cfg.sparsity.shift)
         dens_in = (cfg.sparsity.input_tile_density if cfg.sparsity.enabled
                    else 1.0)
@@ -841,11 +857,13 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
                     else 1.0)
         pf = p["ffn"]
         eff = mask | refresh[:, None]  # refresh ⇒ all rows participate
+        ffn_tiles = sp.no_ffn_tiles(h.shape[0])
         if fast_kernels and dens_in >= 1.0 and dens_ffn >= 1.0:
             # AR fast path: the γ-window eff mask IS a per-token active
             # set, so the whole FFN runs through the fused kernel over eff's
             # tile list at full capacity — up- AND down-projection reads of
-            # fully-masked tiles are physically skipped; masked-off units
+            # tiles no token's mask touches are physically skipped, and
+            # each tile the batch names is read once; masked-off units
             # inside gathered tiles are zeroed in-kernel (unit_mask),
             # matching hh * eff.
             from repro.kernels import fused_decode as kfd
@@ -854,13 +872,13 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
             n_tiles = cfg.d_ff // tile
             idx, nvalid = preds.pack_tile_indices(
                 preds.units_to_tiles(eff, tile), n_tiles)
-            f32, compact = kfd.fused_sparse_ffn(
-                h, pf["wg"] if cfg.ffn_kind == "glu" else pf["wu"],
-                pf["wd"], idx, nvalid,
-                w_up=pf["wu"] if cfg.ffn_kind == "glu" else None,
+            glu = cfg.ffn_kind == "glu"
+            f32, hh = kfd.fused_sparse_ffn(
+                h, ffn_stack["wg" if glu else "wu"], ffn_stack["wd"], idx,
+                nvalid, w_up=ffn_stack["wu"] if glu else None, layer=layer,
                 unit_mask=eff, activation=cfg.activation,
                 shift=cfg.sparsity.shift, tile=tile)
-            hh = kfd.scatter_compact(compact, idx, nvalid, n_tiles)
+            ffn_tiles = kfd.tile_counts(idx, nvalid, n_tiles)
             f = f32.astype(h.dtype)
         else:
             if cfg.ffn_kind == "glu":
@@ -880,7 +898,16 @@ def apply_block_decode_paged(p, x, cfg: ModelConfig, k_pages, v_pages, table,
         scores = tile_activity(hh, _ffn_tile(cfg))
         density = jnp.mean(eff.astype(jnp.float32), axis=-1)
         x = x + f
-        return x, k_pages, v_pages, act, scores, density
+        return x, k_pages, v_pages, act, scores, density, ffn_tiles
+
+
+def _ffn_stack(params, cfg: ModelConfig, fast_kernels: bool):
+    """The FFN weights of every layer, stacked and in the compute dtype,
+    as the fused decode kernel reads them in place (None without fast
+    kernels, whose FFN reads the scan's per-layer slice)."""
+    if not fast_kernels:
+        return None
+    return cm.cast_params(params["layers"]["ffn"], cfg)
 
 
 def decode_step_paged(params, pages, table, token, pos, cfg: ModelConfig,
@@ -894,17 +921,21 @@ def decode_step_paged(params, pages, table, token, pos, cfg: ModelConfig,
     bool γ-window masks. Idle slots point at the scratch block and are
     simply ignored by the caller. Returns (logits (b, vocab_p), pages,
     new_masks (L, b, F), aux) where aux = (act (L, b, F), scores
-    (L, b, F//tile), density (L, b)).
+    (L, b, F//tile), density (L, b), ffn_tiles (tiles named (L, b), tiles
+    fetched (L,)) through the fused kernel, zeros without it).
 
     Structure lives in ``serving_protocol.decode_step_core``; this wrapper
     supplies the dense decode block (same jaxpr as the historical inline
     loop)."""
+    ffn_stack = _ffn_stack(params, cfg, fast_kernels)
+
     def layer_fn(pl_i, li, x, kp, vp, fm):
-        x, kp, vp, act, scores, density = apply_block_decode_paged(
-            pl_i, x, cfg, kp, vp, table, pos, layer=li,
-            block_size=block_size, mask=fm, refresh=refresh,
-            fast_kernels=fast_kernels)
-        return x, kp, vp, (act, scores, density)
+        x, kp, vp, act, scores, density, ffn_tiles = \
+            apply_block_decode_paged(
+                pl_i, x, cfg, kp, vp, table, pos, layer=li,
+                block_size=block_size, mask=fm, refresh=refresh,
+                fast_kernels=fast_kernels, ffn_stack=ffn_stack)
+        return x, kp, vp, (act, scores, density, ffn_tiles)
 
     return sp.decode_step_core(params, pages, token, pos, cfg, ffn_masks,
                                refresh, layer_fn=layer_fn,
@@ -928,20 +959,23 @@ def decode_step_paged_predicted(params, pages, table, token, pos, cfg: ModelConf
     Returns (logits (b, vocab_p), pages, new_masks (L, b, F), aux) with
     aux = (act (L, b, F), scores (L, b, F//tile'), density (L, b) fraction
     of FFN weight tiles read, n_active (L, b), n_miss (L, b) in-graph
-    recall telemetry; zeros when measure=False).
+    recall telemetry; zeros when measure=False; ffn_tiles as in
+    ``decode_step_paged``).
 
     ``shards`` (static; the engine's mesh TP degree) switches the per-token
     packed tile lists to model-axis-local packing — see
     ``_ffn_decode_predicted``. 1 keeps the frozen single-device lowering."""
+    ffn_stack = _ffn_stack(params, cfg, fast_kernels)
+
     def layer_fn(pl_i, li, x, kp, vp, fm, pred_l):
-        x, kp, vp, act, scores, density, n_act, n_miss = \
+        x, kp, vp, act, scores, density, n_act, n_miss, ffn_tiles = \
             apply_block_decode_paged(
                 pl_i, x, cfg, kp, vp, table, pos, layer=li,
                 block_size=block_size, mask=fm, refresh=refresh,
                 pred=pred_l, pred_kind=kind, pred_tile=tile, k_tiles=k_tiles,
                 pred_measure=measure, pred_shards=shards,
-                fast_kernels=fast_kernels)
-        return x, kp, vp, (act, scores, density, n_act, n_miss)
+                fast_kernels=fast_kernels, ffn_stack=ffn_stack)
+        return x, kp, vp, (act, scores, density, n_act, n_miss, ffn_tiles)
 
     return sp.decode_step_core(params, pages, token, pos, cfg, ffn_masks,
                                refresh, layer_fn=layer_fn,

@@ -259,6 +259,14 @@ class EngineObs:
             "repro_decode_context_tokens_total",
             "attention context of the decoding slots, current token "
             "included, summed over decode steps")
+        self.c_ffn_fetched = r.counter(
+            "repro_ffn_tiles_fetched_total",
+            "d_ff tiles the fused FFN kernel read, once per tile any slot "
+            "named, summed over layers and decode steps")
+        self.c_ffn_pairs = r.counter(
+            "repro_ffn_tile_token_pairs_total",
+            "(decoding slot, tile it named) pairs of the fused FFN kernel, "
+            "summed over layers and decode steps")
         self.h_gap = r.histogram(
             "repro_engine_step_gap_seconds",
             "wall time from a step that left work pending to the next "
@@ -425,6 +433,18 @@ class EngineObs:
             if decode_slots:
                 self.c_decode_slots.inc(decode_slots)
                 self.c_decode_context.inc(context_tokens)
+        self.self_time_s += _pc() - t
+
+    def ffn_tiles(self, fetched: int, pairs: int) -> None:
+        """One decode step's fused FFN kernel tile counts, counted in-graph
+        and fetched with the sampled tokens: their ratio is the slots that
+        share each fetched tile. Fast-kernel engines only — the series
+        never exists otherwise."""
+        if not self.enabled:
+            return
+        t = _pc()
+        self.c_ffn_fetched.inc(fetched)
+        self.c_ffn_pairs.inc(pairs)
         self.self_time_s += _pc() - t
 
     def predictor_counts(self, n_active: int, n_miss: int) -> None:

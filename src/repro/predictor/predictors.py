@@ -196,11 +196,12 @@ def covered_tiles(idx: jnp.ndarray, nvalid: jnp.ndarray,
                   n_tiles: int) -> jnp.ndarray:
     """Invert packing: which tiles will actually be gathered. (T, k), (T,)
     -> (T, n_tiles) bool. Differs from the input mask only when packing
-    truncated (more actives than k)."""
-    T, k = idx.shape
+    truncated (more actives than k). Compares over a (T, k, n_tiles)
+    index grid: no scatter."""
+    k = idx.shape[1]
     valid = jnp.arange(k)[None, :] < nvalid[:, None]
-    out = jnp.zeros((T, n_tiles), bool)
-    return out.at[jnp.arange(T)[:, None], idx].max(valid)
+    tiles = jnp.arange(n_tiles, dtype=idx.dtype)
+    return jnp.any((idx[:, :, None] == tiles) & valid[:, :, None], axis=1)
 
 
 # ---------------------------------------------------------------------------

@@ -56,6 +56,16 @@ class GenerationResult:
 # continuous batching
 
 
+def _with_ffn_tiles(nxt, ffn_tiles):
+    """The decode step's (b,) sampled tokens, followed by its fused FFN
+    kernel's tile counts summed over layers — each slot's named tiles (b,),
+    then the tiles fetched — so the one fetch the host makes of the tokens
+    brings the counts, and no other transfer. Integers either way."""
+    named, fetched = ffn_tiles
+    return jnp.concatenate([nxt, jnp.sum(named, 0, dtype=nxt.dtype),
+                            jnp.sum(fetched, dtype=nxt.dtype)[None]])
+
+
 def _place_serve_params(params, mesh):
     """Distribute a param pytree per the serve-mode logical-axis rules
     (weights TP-resident over "model"; sharding/rules.py)."""
@@ -314,7 +324,7 @@ class ContinuousBatchingEngine:
 
         def decode(params, pages, table, token, pos, masks, refresh,
                    temps, tks, tps, keys, gen):
-            logits, pages, new_masks, (act, scores, density) = \
+            logits, pages, new_masks, (act, scores, density, ffn_tiles) = \
                 fam.model_decode_paged(params, pages, table, token, pos, cfg,
                                        masks, refresh, block_size,
                                        fast_kernels=fk)
@@ -323,7 +333,8 @@ class ContinuousBatchingEngine:
             # per-request fraction of active d_ff tiles this step — the
             # granularity the tile-gathered kernels load weights at
             tiles = jnp.mean((scores > 0).astype(jnp.float32), axis=(0, 2))
-            return nxt, lp, pages, new_masks, tiles, jnp.mean(density, 0), act
+            return (_with_ffn_tiles(nxt, ffn_tiles), lp, pages, new_masks,
+                    tiles, jnp.mean(density, 0), act)
 
         def prefill(params, tokens, pages, blocks, true_len,
                     temps, tks, tps, keys):
@@ -412,7 +423,7 @@ class ContinuousBatchingEngine:
             def decode_pred(params, pages, table, token, pos, masks, refresh,
                             pred_params, temps, tks, tps, keys, gen):
                 logits, pages, new_masks, (act, scores, density, n_act,
-                                           n_miss) = \
+                                           n_miss, ffn_tiles) = \
                     fam.model_decode_paged_predicted(
                         params, pages, table, token, pos, cfg, masks,
                         refresh, pred_params, kind, tile_w, k_tiles,
@@ -422,8 +433,8 @@ class ContinuousBatchingEngine:
                                smp.position_keys(keys, gen))
                 tiles = jnp.mean((scores > 0).astype(jnp.float32),
                                  axis=(0, 2))
-                return (nxt, lp, pages, new_masks, tiles,
-                        jnp.mean(density, 0), act,
+                return (_with_ffn_tiles(nxt, ffn_tiles), lp, pages,
+                        new_masks, tiles, jnp.mean(density, 0), act,
                         jnp.sum(n_act, 0), jnp.sum(n_miss, 0))
 
             self._decode_pred = self._jit(decode_pred, donate_argnums=(1, 5))
@@ -734,6 +745,17 @@ class ContinuousBatchingEngine:
                 uid = self.scheduler.slots[i].request.uid
                 self.trackers[uid].update(act_np[:, i, :])
 
+    def _count_ffn_tiles(self, out_np, active) -> np.ndarray:
+        """Split a decode step's fetched tokens from the fused FFN kernel's
+        tile counts that ride with them (``_with_ffn_tiles``), count the
+        tiles fetched and the (active slot, named tile) pairs, and return
+        the (B,) tokens."""
+        B = self.scheduler.n_slots
+        if self.fast_kernels:
+            self.obs.ffn_tiles(int(out_np[2 * B]),
+                               int(out_np[B:2 * B][active].sum()))
+        return out_np[:B]
+
     def step(self) -> bool:
         """Retire finished requests, admit queued ones, advance prefill by
         one chunk (chunked mode), then advance every active slot: one
@@ -793,6 +815,7 @@ class ContinuousBatchingEngine:
             nxt_np, lp_np = np.asarray(nxt), np.asarray(lp)
             self.obs.fetched()
         with st.phase("sample"):
+            nxt_np = self._count_ffn_tiles(nxt_np, active)
             self._account(active, dens_np, tiles_np, act)
             sched.record(nxt_np, lp_np)
 
@@ -818,6 +841,7 @@ class ContinuousBatchingEngine:
             nxt_np, lp_np = np.asarray(nxt), np.asarray(lp)
             self.obs.fetched()
         with st.phase("sample"):
+            nxt_np = self._count_ffn_tiles(nxt_np, active)
             self._account(active, dens_np, tiles_np, act)
             step_act = step_miss = 0
             for i in active:

@@ -29,6 +29,7 @@ from repro.models import common as cm
 from repro.models import registry
 from repro.predictor.predictors import pack_tile_indices
 from repro.serving import ContinuousBatchingEngine
+from repro.serving.config import EngineConfig
 
 from subproc import run_forced_devices as _run
 
@@ -66,7 +67,7 @@ def test_fused_matches_unfused_glu():
     x, wg, wu, wd, idx, nvalid, tile, n_tiles = _case()
     y, h = kfd.fused_sparse_ffn(x, wg, wd, idx, nvalid, w_up=wu,
                                 activation="relu", tile=tile)
-    hh = kfd.scatter_compact(h, idx, nvalid, n_tiles)
+    hh = h
     y0, hh0 = _unfused(x, wg, wu, wd, idx, nvalid, tile)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
     np.testing.assert_array_equal(np.asarray(hh), np.asarray(hh0))
@@ -76,7 +77,7 @@ def test_fused_matches_unfused_mlp():
     x, wg, _, wd, idx, nvalid, tile, n_tiles = _case(seed=3)
     y, h = kfd.fused_sparse_ffn(x, wg, wd, idx, nvalid,
                                 activation="relu", tile=tile)
-    hh = kfd.scatter_compact(h, idx, nvalid, n_tiles)
+    hh = h
     y0, hh0 = _unfused(x, wg, None, wd, idx, nvalid, tile)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
     np.testing.assert_array_equal(np.asarray(hh), np.asarray(hh0))
@@ -91,7 +92,7 @@ def test_fused_matches_unfused_masked_and_shifted():
     y, h = kfd.fused_sparse_ffn(x, wg, wd, idx, nvalid, w_up=wu,
                                 unit_mask=eff, activation="shifted_relu",
                                 shift=0.25, tile=tile)
-    hh = kfd.scatter_compact(h, idx, nvalid, n_tiles)
+    hh = h
     y0, hh0 = _unfused(x, wg, wu, wd, idx, nvalid, tile, unit_mask=eff,
                        shift=0.25)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
@@ -114,7 +115,7 @@ def test_fused_tile_list_property(seed, p):
     idx, nvalid = pack_tile_indices(mask, n_tiles)
     y, h = kfd.fused_sparse_ffn(x, wg, wd, idx, nvalid, w_up=wu,
                                 activation="relu", tile=tile)
-    hh = kfd.scatter_compact(h, idx, nvalid, n_tiles)
+    hh = h
     y0, hh0 = _unfused(x, wg, wu, wd, idx, nvalid, tile)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
     np.testing.assert_array_equal(np.asarray(hh), np.asarray(hh0))
@@ -140,7 +141,7 @@ def test_duplicated_pad_tiles_contribute_exactly_once():
     assert idx[0].tolist() == [2, 2, 2, 2] and nvalid.tolist() == [1, 0]
     y, h = kfd.fused_sparse_ffn(x, wg, wd, idx, nvalid, w_up=wu,
                                 activation="relu", tile=tile)
-    hh = kfd.scatter_compact(h, idx, nvalid, n_tiles)
+    hh = h
     # single-tile reference, computed directly
     sl = slice(2 * tile, 3 * tile)
     h_ref = (jnp.maximum(x[:1] @ wg[:, sl], 0.0) * (x[:1] @ wu[:, sl]))
@@ -149,6 +150,62 @@ def test_duplicated_pad_tiles_contribute_exactly_once():
     np.testing.assert_array_equal(np.asarray(y[0]),
                                   np.asarray(h_ref @ wd[sl])[0])
     assert (np.asarray(y[1]) == 0.0).all() and (np.asarray(hh[1]) == 0.0).all()
+
+
+# per-token tile masks over 4 tiles, and the lists' capacity K
+_UNION_CASES = {
+    "overlapping": ([[1, 1, 0, 0], [0, 1, 1, 0], [1, 0, 1, 1]], 4),
+    "disjoint": ([[1, 0, 0, 0], [0, 1, 0, 0], [0, 0, 1, 1]], 4),
+    "union_smaller_than_n_tiles": ([[0, 1, 0, 0], [0, 1, 0, 0],
+                                    [0, 0, 0, 1]], 4),
+    "token_naming_no_tile": ([[0, 0, 0, 0], [1, 0, 1, 0], [0, 0, 1, 0]], 4),
+    "no_tile_named": ([[0, 0, 0, 0], [0, 0, 0, 0]], 4),
+    "one_token": ([[0, 1, 1, 0]], 4),
+    "pads_at_full_capacity": ([[1, 1, 1, 1], [1, 0, 0, 0], [0, 0, 0, 1]],
+                              4),
+    "truncated_lists": ([[1, 1, 1, 0], [0, 1, 1, 1], [0, 0, 0, 1]], 2),
+}
+
+
+@pytest.mark.parametrize("form", ["predictor", "unit_mask"])
+@pytest.mark.parametrize("case", sorted(_UNION_CASES))
+def test_union_kernel_matches_per_token_pair(case, form):
+    """The kernel walks the batch's UNION of tiles once, all tokens per
+    tile, and still equals the per-token unfused pair bit for bit (f32,
+    d = 64): a token's rows on tiles other tokens named are exact zeros,
+    in h and in y, whether a unit mask is given (the γ path) or not (the
+    predictor path); a token naming no tile gets exact zeros."""
+    rows, K = _UNION_CASES[case]
+    mask = jnp.asarray(rows, bool)
+    T, n_tiles = mask.shape
+    x, wg, wu, wd, _, _, tile, _ = _case(T=T, seed=sorted(_UNION_CASES)
+                                         .index(case))
+    idx, nvalid = pack_tile_indices(mask, K)
+    glu = form == "unit_mask"
+    eff = None
+    if form == "unit_mask":
+        rng = np.random.RandomState(3)
+        eff = jnp.asarray(rng.rand(T, n_tiles * tile) < 0.6)
+    y, hh = kfd.fused_sparse_ffn(x, wg, wd, idx, nvalid,
+                                 w_up=wu if glu else None, unit_mask=eff,
+                                 activation="relu", tile=tile)
+    y0, hh0 = _unfused(x, wg, wu if glu else None, wd, idx, nvalid, tile,
+                       unit_mask=eff)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
+    np.testing.assert_array_equal(np.asarray(hh), np.asarray(hh0))
+    # each token's h is zero outside its own (possibly truncated) list
+    member, uidx, n_union = kfd.union_tiles(idx, nvalid, n_tiles)
+    own = np.zeros((T, n_tiles), bool)
+    for t in range(T):
+        own[t, np.asarray(idx[t, :nvalid[t]])] = True
+    np.testing.assert_array_equal(np.asarray(member), own)
+    assert (np.asarray(hh)[~np.repeat(own, tile, axis=1)] == 0.0).all()
+    assert (np.asarray(y)[~own.any(axis=1)] == 0.0).all()
+    # one ascending list of the union, padded with its last tile
+    union = np.flatnonzero(own.any(axis=0)).tolist()
+    assert int(n_union) == len(union)
+    assert np.asarray(uidx).tolist() == (
+        union + [union[-1] if union else 0] * (n_tiles - len(union)))
 
 
 # ---------------------------------------------------------------------------
@@ -312,6 +369,67 @@ def test_predictor_mode_fast_kernels_byte_identical(name):
     # identical gathered tiles -> identical measured density and savings
     assert abs(e1.weight_io_saved() - e0.weight_io_saved()) < 1e-9
     assert e1.predictor_recall() == e0.predictor_recall()
+
+
+_FFN_COUNTERS = ("repro_ffn_tiles_fetched_total",
+                 "repro_ffn_tile_token_pairs_total")
+
+
+def _spy_ffn_tiles(eng, seen):
+    """Record (active slots, tiles-fetched increment, tile-token-pair
+    increment) for every decode step the engine counts."""
+    reg, inner = eng.obs.registry, eng._count_ffn_tiles
+
+    def count(out_np, active):
+        before = [reg.get(n).value() for n in _FFN_COUNTERS]
+        nxt = inner(out_np, active)
+        after = [reg.get(n).value() for n in _FFN_COUNTERS]
+        seen.append((len(active), after[0] - before[0],
+                     after[1] - before[1]))
+        return nxt
+    eng._count_ffn_tiles = count
+
+
+@pytest.mark.parametrize("reuse", ["refresh_every_token", "sparse_union"])
+def test_ffn_tile_counters(reuse):
+    """The fused kernel's tile counters, counted in-graph and fetched with
+    the tokens: with a refresh on every token each decode step fetches
+    every tile of every layer once, and each active slot names all of
+    them; under γ-masks with a sparse union the tiles fetched are that
+    union, counted here on the host from the masks the step reads."""
+    cfg, fam, params, prompts = _setup("tiny-opt")
+    L, tile = cfg.n_layers, cm.ffn_gather_tile(cfg)
+    n_tiles = cfg.d_ff // tile
+    eng = ContinuousBatchingEngine(cfg, params, config=EngineConfig(
+        n_slots=2, block_size=8, max_blocks_per_seq=6, fast_kernels=True))
+    seen = []
+    _spy_ffn_tiles(eng, seen)
+    if reuse == "refresh_every_token":
+        for p in prompts:
+            eng.submit(p, 6)
+        eng.run()
+        assert any(active == 2 for active, _, _ in seen)
+        for active, fetched, pairs in seen:
+            assert fetched == L * n_tiles
+            assert pairs == L * n_tiles * active
+        return
+    # γ-reuse: after the first (refresh) step both slots ride their masks;
+    # hand them masks whose union is one tile of each layer
+    for p in prompts[:2]:
+        eng.submit(p, 6, reuse_window=100)
+    eng.step()
+    masks = np.zeros((L, 2, cfg.d_ff), bool)
+    for li in range(L):
+        t = li % n_tiles
+        masks[li, 0, t * tile:t * tile + 3] = True
+        masks[li, 1, t * tile + 5] = True
+    eng.masks = jnp.asarray(masks)
+    seen.clear()
+    eng.step()
+    named = masks.reshape(L, 2, n_tiles, tile).any(axis=-1)  # (L, B, tiles)
+    union = named.any(axis=1).sum()
+    assert union < L * n_tiles
+    assert seen == [(2, union, named.sum())]
 
 
 def test_fast_kernels_autodetect_off_on_cpu():

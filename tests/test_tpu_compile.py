@@ -237,3 +237,25 @@ def test_serving_program_touches_pool_in_place_for_v5e(one_chip, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_k
     if program != "prefill":  # whole-prompt prefill runs no kernel
         assert "paged_kv_write" in text and "paged_window_attention" in text
+
+
+@pytest.mark.parametrize("width", ["hd64", "hd128"])
+def test_decode_reads_ffn_weights_in_place_for_v5e(one_chip, monkeypatch,
+                                                   width):
+    """The fast decode step hands the fused FFN kernel the layer scan's
+    whole weight stacks: no layer's (d_model, d_ff) or (d_ff, d_model)
+    projection is sliced or copied out of them first, so the kernel's own
+    DMAs are the step's only FFN weight reads."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _step_cfg(width)
+    fn, args, donated = _step_program(cfg, "decode", one_chip)
+    text = jax.jit(fn, donate_argnums=(donated,)).lower(*args).compile(
+    ).as_text()
+    d, f = cfg.d_model, cfg.d_ff
+    shapes = {f"{d},{f}", f"{f},{d}", f"1,{d},{f}", f"1,{f},{d}"}
+    moved = [f"{name} {op}" for name, dims, op in re.findall(
+                 r"%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", text)
+             if dims in shapes and ("copy" in name or "dynamic-slice" in name
+                                    or op in ("copy", "dynamic-slice"))]
+    assert not moved, moved
+    assert "fused_sparse_ffn" in text
